@@ -130,7 +130,6 @@ def _tracing_overhead():
 
     import jax
 
-    import repro  # noqa: F401  (jax compat shims)
     from repro.configs import get_config
     from repro.core.bucket import BucketTimes
     from repro.core.deft import feedback_solve
@@ -249,4 +248,7 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -80,6 +80,9 @@ def main(argv=None) -> None:
         os.environ.setdefault("BENCH_RUNTIME_STEPS", "6")
         os.environ.setdefault("BENCH_ADAPT_STEPS", "120")
         os.environ.setdefault("BENCH_OBS_STEPS", "20")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     t0 = time.time()
     failures = 0

@@ -3,8 +3,10 @@ implementation, plus solver planning time with/without memoization.
 
 Emits machine-readable ``BENCH_runtime.json`` (steps/s, compile time,
 solver planning time, collectives-per-phase) so the perf trajectory is
-tracked across PRs.  Two train-loop scenarios, each in its own
-subprocess:
+tracked across PRs.  Every JAX section runs in a subprocess of its own,
+one after another, and the parent never imports JAX, so on an
+accelerator each child holds the device alone.  Two of the train-loop
+scenarios:
 
 * ``smoke`` — the smoke DeFT train loop exactly as ``repro.launch.train
   --smoke --scheduler deft`` runs it on this host (single device).  The
@@ -116,7 +118,6 @@ def _inner(devices: int) -> dict:
         )
     import jax
 
-    import repro  # noqa: F401  (jax compat shims)
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.bucket import BucketTimes
     from repro.core.deft import solve_schedule
@@ -276,7 +277,6 @@ def _inner_fsdp() -> dict:
     import jax
     import jax.numpy as jnp
 
-    import repro  # noqa: F401
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.bucket import BucketTimes
     from repro.core.deft import solve_schedule
@@ -434,7 +434,6 @@ def _inner_decoupled() -> dict:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
 
-    import repro  # noqa: F401
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.bucket import BucketTimes
     from repro.core.deft import Planner, PlanRequest, ag_times, rs_times
@@ -572,7 +571,6 @@ def _inner_precision() -> dict:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
 
-    import repro  # noqa: F401
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.bucket import BucketTimes
     from repro.core.deft import Planner, PlanRequest
@@ -707,7 +705,6 @@ def _inner_two_link() -> dict:
 
     import jax
 
-    import repro  # noqa: F401
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.bucket import BucketTimes
     from repro.core.deft import Planner, PlanRequest, ag_times, rs_times
@@ -867,7 +864,6 @@ def _bench_update_path() -> dict:
     """
     import jax
 
-    import repro  # noqa: F401
     from repro.configs import get_config, reduce_for_smoke
     from repro.kernels.bucket_update import (
         apply_bucket_updates,
@@ -931,7 +927,6 @@ def _bench_repack(step_s_smoke: float) -> dict:
     every O(100) steps at realistic replan cadence)."""
     import jax
 
-    import repro  # noqa: F401
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.deft import solve_schedule
     from repro.core.scheduler import SchedulerConfig
@@ -1067,35 +1062,41 @@ def _bench_solver() -> dict:
     }
 
 
+def _child(name: str, *args: str) -> dict:
+    """Run one JAX section in its own process and return its JSON line.
+    The parent never imports JAX, so on an accelerator each child holds
+    the device alone, one after another."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), *args],
+        env=env, capture_output=True, text=True, timeout=1800,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"runtime bench ({name}) failed:\n{proc.stderr[-2000:]}"
+        )
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
 def run() -> None:
     """Benchmark section entry point (benchmarks/run.py)."""
     t0 = time.time()
-    results: dict = {
-        "solver": _bench_solver(),
-        "update_path": _bench_update_path(),
-    }
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    for name, args in (("smoke", ["--inner", "1"]),
+    results: dict = {"solver": _bench_solver()}   # pure Python, no JAX
+    for name, args in (("update_path", ["--inner-update-path"]),
+                       ("smoke", ["--inner", "1"]),
                        ("dp4", ["--inner", "4"]),
                        ("fsdp_flat", ["--inner-fsdp"]),
                        ("decoupled", ["--inner-decoupled"]),
                        ("precision", ["--inner-precision"]),
                        ("two_link", ["--inner-two-link"])):
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), *args],
-            env=env, capture_output=True, text=True, timeout=1800,
-        )
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"runtime bench ({name}) failed:\n{proc.stderr[-2000:]}"
-            )
-        results[name] = json.loads(proc.stdout.splitlines()[-1])
+        results[name] = _child(name, *args)
 
-    # repack rides in-process AFTER the scenarios: the smoke scenario's
-    # steady-state step time is its amortization denominator
-    results["repack"] = _bench_repack(
-        1.0 / results["smoke"]["steps_per_s_fused"]
+    # repack runs AFTER the scenarios: the smoke scenario's steady-state
+    # step time is its amortization denominator
+    results["repack"] = _child(
+        "repack", "--inner-repack",
+        repr(1.0 / results["smoke"]["steps_per_s_fused"]),
     )
 
     tmp = _OUT + ".tmp"
@@ -1204,8 +1205,18 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    if len(sys.argv) > 1 and sys.argv[1].startswith("--inner"):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     if len(sys.argv) > 2 and sys.argv[1] == "--inner":
         json.dump(_inner(int(sys.argv[2])), sys.stdout)
+        print()
+    elif len(sys.argv) > 1 and sys.argv[1] == "--inner-update-path":
+        json.dump(_bench_update_path(), sys.stdout)
+        print()
+    elif len(sys.argv) > 2 and sys.argv[1] == "--inner-repack":
+        json.dump(_bench_repack(float(sys.argv[2])), sys.stdout)
         print()
     elif len(sys.argv) > 1 and sys.argv[1] == "--inner-fsdp":
         json.dump(_inner_fsdp(), sys.stdout)

@@ -111,8 +111,6 @@ fi
 
 if [[ "$mode" != "--fast" ]]; then
   echo "== dry-run smoke (compile-only, no model memory) =="
-  # default (ddp) mode: --mode deft needs jax >= 0.5 on the production
-  # mesh (partial-manual SPMD CHECK on old jaxlib — DESIGN.md §6).
   # Output goes to a scratch dir: the checked-in experiments/dryrun
   # artifacts are updated deliberately, not by every verify run (CI
   # asserts the tree is clean afterwards).

@@ -1,10 +1,1 @@
-"""repro: DeFT (flexible communication scheduling) reproduction on JAX.
-
-Importing any ``repro.*`` module activates the jax version-compat shims
-(see ``repro.util.jax_compat``) so the new-jax API surface used across
-the codebase and tests also runs on the older jax pinned in the CI
-container.
-"""
-from repro.util.jax_compat import install as _install_jax_compat
-
-_install_jax_compat()
+"""repro: DeFT (flexible communication scheduling) reproduction on JAX."""

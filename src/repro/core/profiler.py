@@ -19,16 +19,69 @@ from repro.core.bucket import Bucket, BucketTimes, build_buckets, model_layer_el
 
 
 @dataclasses.dataclass(frozen=True)
-class HardwareModel:
-    """TPU v5e-like chip + interconnect model (assignment constants)."""
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
 
-    chip_flops: float = 197e12        # bf16 peak FLOP/s
-    hbm_bw: float = 819e9             # bytes/s
-    ici_bw: float = 50e9              # bytes/s per link (primary)
+    bf16_flops: float                 # FLOP/s
+    hbm_bw: float                     # bytes/s
+    ici_link_bw: float                # bytes/s per chip-to-chip link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.  A TPU whose kind is missing is an
+# error (:func:`peaks_for`), never a silent default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interconnect per chip over four links
+        ici_link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+# The chip the planner prices for when no TPU is attached (CPU runs).
+PLANNING_TARGET_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add a "
+            f"row to core.profiler.DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)})"
+        ) from None
+
+
+_TARGET = DEVICE_PEAKS[PLANNING_TARGET_KIND]
+
+
+@dataclasses.dataclass(frozen=True)
+class HardwareModel:
+    """Chip + interconnect model the planner prices buckets with.  The
+    defaults are the planning target's peaks; :meth:`for_device` reads
+    an attached TPU's own row."""
+
+    chip_flops: float = _TARGET.bf16_flops    # bf16 peak FLOP/s
+    hbm_bw: float = _TARGET.hbm_bw            # bytes/s
+    ici_bw: float = _TARGET.ici_link_bw       # bytes/s per link (primary)
     mu: float = 1.65                  # primary/secondary speed ratio (paper)
     mfu: float = 0.45                 # assumed compute efficiency
     dp_degree: int = 16               # devices participating in grad allreduce
     grad_bytes_per_elem: int = 4      # fp32 gradient sync
+
+    @classmethod
+    def for_device(cls, device, **overrides) -> "HardwareModel":
+        """The model for ``device`` (a ``jax.Device``): a TPU is priced
+        from its ``device_kind`` row of :data:`DEVICE_PEAKS` and an
+        unknown TPU kind raises; other platforms price the planning
+        target."""
+        kind = (device.device_kind if device.platform == "tpu"
+                else PLANNING_TARGET_KIND)
+        p = peaks_for(kind)
+        return cls(chip_flops=p.bf16_flops, hbm_bw=p.hbm_bw,
+                   ici_bw=p.ici_link_bw, **overrides)
 
     @property
     def secondary_bw(self) -> float:

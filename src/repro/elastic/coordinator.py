@@ -110,8 +110,13 @@ def migrate_state(old_rt, new_rt, state) -> Any:
     state.pop("pgather", None)
     n_old, n_new = old_rt.accum_devices, new_rt.accum_devices
     if n_old != n_new:
-        state["cur"] = tuple(fold_accum_rows(b, n_new) for b in state["cur"])
-        state["fut"] = tuple(fold_accum_rows(b, n_new) for b in state["fut"])
+        # the state still lives on the old mesh, whatever mesh the caller
+        # has set around this call
+        with jax.set_mesh(old_rt.mesh):
+            state["cur"] = tuple(fold_accum_rows(b, n_new)
+                                 for b in state["cur"])
+            state["fut"] = tuple(fold_accum_rows(b, n_new)
+                                 for b in state["fut"])
     state = jax.device_put(state, NamedSharding(new_rt.mesh, P()))
     tr = build_layout_transition(old_rt.layout, new_rt.layout)
     with jax.set_mesh(new_rt.mesh):

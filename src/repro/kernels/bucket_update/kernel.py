@@ -22,10 +22,9 @@ tensor (launch, dispatch, tree bookkeeping) is paid once per bucket.
 * Dynamic scalars (grad scale, clip, lr, bias corrections) ride in one
   (1, 128) f32 row broadcast to every program (ops.SCALARS_* layout).
 
-In-place semantics come from ``input_output_aliases`` (gated on
-jax_compat.PALLAS_BUCKET_ALIAS_OK — on older jaxlibs the jit-level
-donation still reuses the buffers).  The pure-JAX twin in ref.py computes
-the same expressions in the same order, so the two bit-match.
+In-place semantics come from ``input_output_aliases``.  The pure-JAX
+twin in ref.py computes the same expressions in the same order, so the
+two bit-match.
 """
 from __future__ import annotations
 
@@ -37,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.optim.optimizers import OptimizerSpec
-from repro.util.jax_compat import PALLAS_BUCKET_ALIAS_OK
 
 _LANES = 128
 
@@ -175,12 +173,10 @@ def bucket_update_pallas(
     out_specs = [row_spec] * n_out
 
     # operand k of (p, m, [v], g) aliases output k: in-place update
-    aliases = {}
-    if PALLAS_BUCKET_ALIAS_OK and not interpret:
-        n_state = 3 if adam else 2
-        aliases = {1 + k: k for k in range(n_state)}
-        if zero_grads:
-            aliases[1 + n_state] = n_state    # g -> zeroed accumulator
+    n_state = 3 if adam else 2
+    aliases = {1 + k: k for k in range(n_state)}
+    if zero_grads:
+        aliases[1 + n_state] = n_state    # g -> zeroed accumulator
 
     kernel = functools.partial(
         _update_kernel,
@@ -199,6 +195,7 @@ def bucket_update_pallas(
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="bucket_update",
     )(*operands)
     out = [o.reshape(padded) for o in out]
     p_new, m_new = out[0], out[1]

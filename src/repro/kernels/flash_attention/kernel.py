@@ -9,12 +9,15 @@ MXU work is never issued), which is what makes local attention
 sub-quadratic on the long_500k path.
 
 Layout: q [B, H, Sq, D], k/v [B, KV, Sk, D]; GQA is handled in the
-BlockSpec index_map (head h reads kv head h // (H // KV)).
+BlockSpec index_map (head h reads kv head h // (H // KV)).  Besides the
+output the kernel writes each row's log-sum-exp [B, H, Sq], the one
+residual the backward pass (ops.py) needs to recompute probabilities.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,7 @@ NEG_INF = -1e30
 
 
 def _attn_kernel(
-    q_ref, k_ref, v_ref, o_ref,  # VMEM tiles
+    q_ref, k_ref, v_ref, o_ref, lse_ref,  # VMEM tiles
     acc_ref, m_ref, l_ref,       # scratch
     *,
     causal: bool,
@@ -92,6 +95,7 @@ def _attn_kernel(
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0, ...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        lse_ref[...] = (m_ref[...] + jnp.log(l))[None, :]
 
 
 @functools.partial(
@@ -111,7 +115,8 @@ def flash_attention_pallas(
     block_q: int = 128,
     block_kv: int = 128,
     interpret: bool = False,
-) -> jax.Array:
+) -> Tuple[jax.Array, jax.Array]:
+    """Returns ``(out [B, H, Sq, D], lse [B, H, Sq] f32)``."""
     b, h, sq, d = q.shape
     _, kvh, sk, _ = k.shape
     assert h % kvh == 0
@@ -132,7 +137,7 @@ def flash_attention_pallas(
         sm_scale=sm_scale,
         num_kv_blocks=nk,
     )
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
@@ -146,14 +151,26 @@ def flash_attention_pallas(
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_specs=[
+            pl.BlockSpec(
+                (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+            ),
+            # a unit sublane dim keeps the block a legal (1, 128k) tile
+            pl.BlockSpec(
+                (None, None, 1, block_q),
+                lambda ib, ih, iq, ik: (ib, ih, 0, iq),
+            ),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
+    return out, lse[:, :, 0]

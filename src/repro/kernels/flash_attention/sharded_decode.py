@@ -44,10 +44,8 @@ def sharded_flash_decode(
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
 
     # Global KV positions enter as an operand sharded like the cache
-    # instead of being derived from jax.lax.axis_index: axis_index lowers
-    # to a PartitionId instruction that the SPMD partitioner rejects
-    # inside partial-manual regions (jaxlib < 0.5), and an explicit iota
-    # operand partitions fine everywhere.
+    # instead of being derived from jax.lax.axis_index, so the body
+    # needs no PartitionId inside the partial-manual region.
     positions = jnp.arange(s_total, dtype=jnp.int32)
 
     def body(qb, kb, vb, lenb, kpos):

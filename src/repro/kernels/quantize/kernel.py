@@ -92,6 +92,7 @@ def stochastic_round_bf16_pallas(
         out_specs=pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.bfloat16),
         interpret=interpret,
+        name="stochastic_round_bf16",
     )(seed_row, x.reshape(rows, _LANES))
     return out.reshape(padded)
 
@@ -134,6 +135,7 @@ def quantize_int8_pallas(
             jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_int8",
     )(x.reshape(rows, _LANES))
     return q.reshape(padded), s[:, 0]
 
@@ -170,5 +172,6 @@ def dequantize_int8_pallas(
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
+        name="dequantize_int8",
     )(q.reshape(rows, _LANES), s2)
     return out.reshape(padded)

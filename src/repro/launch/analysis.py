@@ -5,8 +5,8 @@ cost_analysis, so we parse the compiled HLO text and sum the operand sizes
 of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute instruction.
 
-Hardware constants are the assignment's TPU-v5e numbers:
-197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants come from the peaks table ``core.profiler.DEVICE_PEAKS``
+(the dry runs target TPU v5e).
 """
 from __future__ import annotations
 
@@ -15,9 +15,12 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12         # bf16 per chip
-HBM_BW = 819e9              # bytes/s per chip
-ICI_BW = 50e9               # bytes/s per link
+from repro.core.profiler import PLANNING_TARGET_KIND, peaks_for
+
+_PEAKS = peaks_for(PLANNING_TARGET_KIND)
+PEAK_FLOPS = _PEAKS.bf16_flops     # per chip
+HBM_BW = _PEAKS.hbm_bw             # bytes/s per chip
+ICI_BW = _PEAKS.ici_link_bw        # bytes/s per link
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -162,8 +165,6 @@ def analyse_compiled(
     costs; collective bytes parsed from HLO are likewise per device.
     """
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     bytes_ = float(ca.get("bytes accessed", 0.0))
     try:

@@ -95,8 +95,6 @@ def _variant_cfg(cfg, k: int):
 
 def _metrics(compiled) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),

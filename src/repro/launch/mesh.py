@@ -7,18 +7,8 @@ from __future__ import annotations
 
 import jax
 
-from repro.util.jax_compat import install as _install_jax_compat
-
-_install_jax_compat()
-
 
 def _auto_axis_types(n: int):
-    """axis_types kwarg value across jax versions.
-
-    Newer jax exposes ``jax.sharding.AxisType`` natively; on older jax the
-    compat shim provides a stand-in and ``jax.make_mesh`` ignores the
-    kwarg (0.4.x meshes are implicitly all-Auto).
-    """
     return (jax.sharding.AxisType.Auto,) * n
 
 
@@ -43,14 +33,9 @@ def make_elastic_mesh(device_rows):
     arr = np.array(device_rows, dtype=object)
     if arr.ndim == 1:
         arr = arr.reshape(-1, 1)
-    try:
-        return jax.sharding.Mesh(
-            arr, ("data", "model"), axis_types=_auto_axis_types(2)
-        )
-    except (TypeError, AttributeError):
-        # older jax: Mesh has no axis_types kwarg, or expects a dict form
-        # — match what the make_mesh compat shim produces (all-Auto)
-        return jax.sharding.Mesh(arr, ("data", "model"))
+    return jax.sharding.Mesh(
+        arr, ("data", "model"), axis_types=_auto_axis_types(2)
+    )
 
 
 def make_debug_mesh(data: int = 4, model: int = 2, pod: int = 0):

@@ -1,19 +1,25 @@
 """Training driver: DDP baseline or the full DeFT pipeline
 (Profiler -> Solver -> Preserver -> per-phase compiled steps).
 
-On this CPU container it drives reduced configs over the debug mesh (set
+On a CPU it drives reduced configs over the debug mesh (set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before launch for
-a multi-device mesh); pointed at a TPU slice it drives the same code over
-``make_production_mesh()``.
+a multi-device mesh); on a TPU it drives the same code over the attached
+chips, or ``make_production_mesh()`` on a full slice.  ``--layers N``
+keeps a config's published widths and cuts only its depth, which is how
+a model larger than one chip's memory trains there.
 
     PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --smoke \
         --scheduler deft --steps 60
+    python -m repro.launch.train --arch qwen3-4b --layers 2 \
+        --compute-dtype bf16 --batch 8 --seq 512 --steps 8
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import signal
 import time
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +61,7 @@ from repro.elastic import (
 )
 from repro.models.model import init_params
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Tracer, format_event
 from repro.optim.optimizers import adamw
 from repro.sharding.specs import needs_fsdp
@@ -69,10 +76,30 @@ from repro.train.runtime import DeftRuntime, RuntimeConfig, make_ddp_step
 from repro.train.steps import init_train_state
 
 
+# Largest f32 logits block the LM head may hold at once; above it the
+# loss runs sequence-chunked (models.model.chunked_ce).
+LOGITS_BUDGET_BYTES = 256 * 2**20
+
+
+def loss_chunk_for(vocab_size: int, per_device_batch: int,
+                   seq_len: int) -> int:
+    """Sequence chunk of the LM head + loss: 0 (whole sequence) when the
+    ``[B, S, V]`` f32 logits fit :data:`LOGITS_BUDGET_BYTES`, else the
+    largest power of two whose ``[B, chunk, V]`` block does."""
+    row = 4 * vocab_size * per_device_batch
+    if row * seq_len <= LOGITS_BUDGET_BYTES:
+        return 0
+    chunk = 1
+    while chunk * 2 * row <= LOGITS_BUDGET_BYTES and chunk * 2 < seq_len:
+        chunk *= 2
+    return chunk
+
+
 def build_schedule(
     params,
     cfg,
     *,
+    hw: HardwareModel,
     dp: int,
     seq_len: int,
     per_device_batch: int,
@@ -94,7 +121,6 @@ def build_schedule(
     the returned ``PlanResult`` carries the adopted policy.
     """
     bucket_of, nb = assign_buckets(params, cfg, partition_elems)
-    hw = HardwareModel(dp_degree=dp)
     times = leaf_bucket_times(params, cfg, bucket_of, nb, hw, seq_len,
                               per_device_batch)
     if coverage_rate > 0:
@@ -181,15 +207,27 @@ def restore_runtime_state(runtime, ckpt_dir: str, params_abs):
     return None, 0
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
+    """Parse ``argv`` (default: the command line), train, and return what
+    the run measured: ``losses`` and blocked per-step wall times
+    ``step_s`` (one each per step), ``updated`` flags, ``compile_s`` (the
+    DeFT AOT compile), ``donation_held`` (every step consumed its input
+    state), the DeFT ``runtime`` (None for DDP), and
+    ``cfg``/``n_params``/``mesh``/``loss_chunk``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="gemma2-2b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-friendly)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep the published widths and cut the depth to "
+                         "this many layers (0 = the config's own depth)")
     ap.add_argument("--scheduler", choices=["ddp", "deft"], default="deft")
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
+    ap.add_argument("--lr", type=float, default=1e-3,
+                    help="AdamW learning rate (the default suits the "
+                         "smoke widths; published widths want less)")
     ap.add_argument("--coverage-rate", type=float, default=1.8,
                     help="synthetic CR for the DeFT schedule (0 = analytic)")
     ap.add_argument("--partition-elems", type=int, default=200_000)
@@ -229,8 +267,9 @@ def main() -> None:
                          "on an unsurvivable fault")
     ap.add_argument("--compute-dtype", choices=["f32", "bf16"],
                     default="f32",
-                    help="forward/backward precision of the flat engines "
-                         "(the master copy stays f32)")
+                    help="forward/backward precision: the flat DeFT "
+                         "engines keep an f32 master copy; DDP keeps its "
+                         "whole state at this dtype")
     ap.add_argument("--wire-precision",
                     choices=["auto", "f32", "bf16", "int8"],
                     default="f32",
@@ -262,7 +301,12 @@ def main() -> None:
                          "before training (a checkpoint written under a "
                          "different bucket layout is re-packed through "
                          "the LayoutTransition)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.layers and args.smoke:
+        ap.error("--layers cuts the depth at published widths; --smoke "
+                 "already shrinks the whole config")
+    if args.layers < 0:
+        ap.error("--layers must be >= 0")
     if args.elastic and args.adapt:
         ap.error("--elastic and --adapt are mutually exclusive: the "
                  "elastic controller owns replanning while it owns the "
@@ -271,9 +315,12 @@ def main() -> None:
         ap.error("--elastic needs --scheduler deft (the migration path "
                  "repacks the flat DeFT state)")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     # one tracer for the whole run: runtime step/phase spans, controller
     # replans, elastic lifecycle — all in one clock domain (DESIGN.md §11)
     tracer = Tracer() if args.trace else None
@@ -286,19 +333,30 @@ def main() -> None:
         mesh = make_debug_mesh(data=data, model=model)
     dp = dict(zip(mesh.axis_names, mesh.devices.shape))["data"]
     fsdp = needs_fsdp(cfg.name)
-    opt = adamw(1e-3)
+    opt = adamw(args.lr)
     key = jax.random.PRNGKey(args.seed)
+    # the planner prices the attached chip; an unknown TPU kind raises
+    hw = HardwareModel.for_device(jax.devices()[0], dp_degree=dp)
+    loss_chunk = loss_chunk_for(cfg.vocab_size, max(args.batch // dp, 1),
+                                args.seq)
 
-    print(f"arch={cfg.name} params={cfg.total_params():,} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
+    print(f"arch={cfg.name} layers={cfg.n_layers} "
+          f"params={cfg.total_params():,} "
+          f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
+          f"loss_chunk={loss_chunk}")
     ds = SyntheticDataset(cfg, args.seed, args.batch, args.seq)
 
+    compute_dtype = jnp.bfloat16 if args.compute_dtype == "bf16" else None
+    compile_s = 0.0
     with jax.set_mesh(mesh):
         runtime = None
         start_step = 0
         if args.scheduler == "ddp":
-            state = init_train_state(key, cfg, opt)
+            state = init_train_state(key, cfg, opt,
+                                     dtype=compute_dtype or jnp.float32)
             # donated: params/opt update in place instead of copying
-            step_fn = make_ddp_step(cfg, opt, fsdp=fsdp)
+            step_fn = make_ddp_step(cfg, opt, fsdp=fsdp,
+                                    loss_chunk=loss_chunk)
             if args.resume and args.ckpt:
                 last = latest_step(args.ckpt)
                 if last is not None:
@@ -312,7 +370,7 @@ def main() -> None:
                 lambda k: init_params(k, cfg), jax.random.PRNGKey(0)
             )
             bucket_of, nb, times, plan = build_schedule(
-                params_abs, cfg, dp=dp, seq_len=args.seq,
+                params_abs, cfg, hw=hw, dp=dp, seq_len=args.seq,
                 per_device_batch=max(args.batch // dp, 1),
                 partition_elems=args.partition_elems,
                 coverage_rate=args.coverage_rate,
@@ -337,10 +395,8 @@ def main() -> None:
                                          shard_count=dp if fsdp else 1)
             if plan.precision is not None:
                 layout = layout.with_precision(plan.precision)
-            compute_dtype = (jnp.bfloat16 if args.compute_dtype == "bf16"
-                             else None)
             rcfg = RuntimeConfig(
-                fsdp=fsdp, compute_dtype=compute_dtype,
+                fsdp=fsdp, compute_dtype=compute_dtype, loss_chunk=loss_chunk,
                 decoupled=args.decoupled,
                 master_dtype=(args.master_dtype
                               if args.master_dtype != "f32" else None),
@@ -356,13 +412,14 @@ def main() -> None:
                 state = runtime.init_state(
                     key, dtype=compute_dtype or jnp.float32
                 )
-            t_c = time.time()
+            t_c = time.perf_counter()
             # AOT phase cache against abstract batch specs: no data batch
             # is consumed, so step 0 still trains on the stream's batch 0
             runtime.compile(state, batch_spec(cfg, args.batch, args.seq))
+            compile_s = time.perf_counter() - t_c
             st = runtime.stats()
             print(f"compiled {runtime.n_unique_phases} unique phases "
-                  f"(period {runtime.period}) in {time.time() - t_c:.1f}s; "
+                  f"(period {runtime.period}) in {compile_s:.1f}s; "
                   f"max collectives in a phase: "
                   f"{st['max_collectives_in_a_phase']} "
                   f"(vs {layout.n_leaves} per-leaf); "
@@ -379,7 +436,7 @@ def main() -> None:
         if args.adapt and runtime is not None:
             if args.adapt_repartition:
                 model = build_leaf_time_model(
-                    params_abs, cfg, HardwareModel(dp_degree=dp),
+                    params_abs, cfg, hw,
                     args.seq, max(args.batch // dp, 1),
                 )
                 if args.coverage_rate > 0:
@@ -419,7 +476,8 @@ def main() -> None:
 
             def model_for(width: int):
                 m = build_leaf_time_model(
-                    params_abs, cfg, HardwareModel(dp_degree=width),
+                    params_abs, cfg,
+                    dataclasses.replace(hw, dp_degree=width),
                     args.seq, max(args.batch // width, 1),
                 )
                 if args.coverage_rate > 0:
@@ -469,6 +527,8 @@ def main() -> None:
             signal.signal(signal.SIGTERM, _on_preempt)
             signal.signal(signal.SIGUSR1, _on_preempt)
 
+        losses, step_s, updated = [], [], []
+        donation_held = True
         t0 = time.time()
         # a resumed run continues the data stream where it left off —
         # otherwise steps N.. would retrain on batches 0.. and diverge
@@ -498,6 +558,7 @@ def main() -> None:
                 last_step = step - 1
                 break
             batch = next(ds)
+            prev = state
             t_s = time.perf_counter()
             try:
                 if runtime is None:
@@ -514,12 +575,21 @@ def main() -> None:
                 halted = True
                 last_step = step - 1
                 break
+            # the step's wall time ends when its outputs are ready, not
+            # when the dispatch returns
+            jax.block_until_ready((state, m))
+            wall = time.perf_counter() - t_s
+            step_s.append(wall)
+            losses.append(float(m["loss"]))
+            updated.append(bool(m["updated"]))
+            donation_held &= all(
+                x.is_deleted() for x in jax.tree_util.tree_leaves(prev)
+            )
+            del prev
             if tracer is not None:
                 tracer.add("step", f"step{step}", t_s, tracer.now(),
                            step=step)
             if elastic is not None:
-                jax.block_until_ready(m["loss"])
-                wall = time.perf_counter() - t_s
                 if scenario is not None:
                     obs = scenario.observe(step, wall)
                     if obs.notices:
@@ -548,13 +618,11 @@ def main() -> None:
                     )
                     cold = None     # synthetic walls: no dispatch pollution
                 else:
-                    jax.block_until_ready(m["loss"])
-                    wall = time.perf_counter() - t_s
                     # first-dispatch tag: a wall that includes an
                     # executable's one-off lazy work never enters the EMAs
                     cold = runtime.last_dispatch_first
                 event = controller.observe(
-                    step, runtime.last_phase, wall, loss=float(m["loss"]),
+                    step, runtime.last_phase, wall, loss=losses[-1],
                     cold=cold,
                 )
                 if event is not None:
@@ -608,8 +676,8 @@ def main() -> None:
                     )
             if (step - start_step) % max(args.steps // 10, 1) == 0 \
                     or step == last_step:
-                print(f"step {step:4d} loss={float(m['loss']):.4f} "
-                      f"updated={bool(m['updated'])}")
+                print(f"step {step:4d} loss={losses[-1]:.4f} "
+                      f"updated={updated[-1]} step_s={step_s[-1]:.4f}")
         dt = time.time() - t0
         print(f"{args.steps} steps in {dt:.1f}s "
               f"({args.steps * args.batch * args.seq / dt:.0f} tok/s)")
@@ -653,6 +721,18 @@ def main() -> None:
         dropped = (f", {ts['dropped']} dropped (ring full)"
                    if ts["dropped"] else "")
         print(f"trace -> {args.trace} ({ts['retained']} spans{dropped})")
+    return {
+        "cfg": cfg,
+        "n_params": cfg.total_params(),
+        "mesh": dict(zip(mesh.axis_names, mesh.devices.shape)),
+        "losses": losses,
+        "step_s": step_s,
+        "updated": updated,
+        "compile_s": compile_s,
+        "donation_held": donation_held,
+        "runtime": runtime,
+        "loss_chunk": loss_chunk,
+    }
 
 
 if __name__ == "__main__":

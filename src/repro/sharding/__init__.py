@@ -56,13 +56,24 @@ def _axis_prod(mesh_shape: Dict[str, int], axis: AxisVal) -> int:
 def spec_for(names: Sequence[Optional[str]], shape=None) -> P:
     """PartitionSpec for a tuple of logical dim names under active rules.
     Axes whose dimension does not divide the mesh axis product are dropped
-    (replicated) — e.g. 36 heads over a 16-way 'model' axis."""
+    (replicated) — e.g. 36 heads over a 16-way 'model' axis.  So are axes
+    an enclosing shard_map made manual: the data there is already local,
+    and a constraint may name only auto axes."""
     rules = _current_rules() or {}
     mesh = jax.sharding.get_abstract_mesh()
     mesh_shape = dict(getattr(mesh, "shape", {}) or {})
+    manual = {
+        a for a, t in zip(getattr(mesh, "axis_names", ()),
+                          getattr(mesh, "axis_types", ()))
+        if t == jax.sharding.AxisType.Manual
+    }
     out = []
     for i, n in enumerate(names):
         axis = rules.get(n) if n else None
+        if axis is not None and manual:
+            kept = tuple(a for a in ((axis,) if isinstance(axis, str)
+                                     else axis) if a not in manual)
+            axis = (kept[0] if len(kept) == 1 else kept) or None
         if axis is not None and shape is not None:
             if shape[i] % _axis_prod(mesh_shape, axis) != 0:
                 axis = None

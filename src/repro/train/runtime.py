@@ -36,9 +36,11 @@ tree-state fused path remains available via ``flat_state=False``.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import contextlib
 import dataclasses
 import functools
+import re
 import threading
 import time
 import warnings
@@ -46,6 +48,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -705,15 +708,30 @@ def _flat_rs_state_specs(
 def _shard_phase(body, specs_fn, state, batch, mesh, dp_axes):
     """The one shard_map invocation every phase wrapper shares (state
     specs from ``specs_fn``, batch split over DP, fused metric specs)."""
-    in_specs = (specs_fn(state, dp_axes), _batch_specs(batch, dp_axes))
-    return jax.shard_map(
+    state_specs = specs_fn(state, dp_axes)
+    # size-1 axes go manual too: a Pallas kernel (attention, bucket
+    # update) cannot be partitioned over an auto axis, and a size-1 axis
+    # shards nothing
+    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
+    manual = set(dp_axes) | {a for a, n in shape.items() if n == 1}
+    new_state, metrics = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(specs_fn(state, dp_axes), _METRIC_SPECS),
-        axis_names=set(dp_axes),
+        in_specs=(state_specs, _batch_specs(batch, dp_axes)),
+        out_specs=(state_specs, _METRIC_SPECS),
+        axis_names=manual,
         check_vma=False,
     )(state, batch)
+    # pin the state's output placement to its input placement: left to
+    # the partitioner, an auto (non-manual) axis may come back split (a
+    # bucket sharded over 'model'), and a donated buffer aliases only an
+    # output of the same sharding
+    new_state = jax.tree.map(
+        lambda x, s: jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, s)),
+        new_state, state_specs,
+    )
+    return new_state, metrics
 
 
 def deft_phase_step_flat(
@@ -789,12 +807,6 @@ def deft_rs_phase_step_flat(
     Unlike the tree-state RS path (manual over 'pod' only, FSDP left to
     XLA), the whole DP hierarchy is explicit here, so the engine also
     runs on single-pod meshes — 'pod' is simply absent from the sync.
-
-    Old-jaxlib caveat (composes with DESIGN.md §6): the tiled
-    psum_scatter/all_gather chain partitions correctly inside a
-    partial-manual region only when the auto (model) axis is size 1 on
-    jaxlib < 0.5; real TP + this engine needs jax >= 0.5 — the same
-    constraint the tree RS path already has.
     """
     shard_axis = "data"
     assert shard_axis in mesh.axis_names, "sharded flat engine needs 'data'"
@@ -897,6 +909,13 @@ def deft_rs_phase_step_fused(
 # ---------------------------------------------------------------------------
 # Collective accounting (static, from the phase spec)
 # ---------------------------------------------------------------------------
+# an HLO instruction that calls a Pallas TPU kernel; group 1 is the name
+# the kernel was given (``pallas_call(name=...)``)
+_TPU_KERNEL_RE = re.compile(
+    r"%([\w-]+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\""
+)
+
+
 def phase_collectives(phase: PhaseSpec) -> Dict[str, int]:
     """Collectives one fused phase issues, by construction: one primary
     psum per primary-synced bucket, one reduce-scatter chain per
@@ -948,12 +967,16 @@ class _PhaseEntry:
     reuses its compiled executable verbatim, including across layout
     swaps that later return to a previously-seen layout."""
 
-    __slots__ = ("spec", "jitted", "compiled", "stats")
+    __slots__ = ("spec", "jitted", "compiled", "batch_sharding", "stats")
 
     def __init__(self, spec: PhaseSpec, jitted: Callable):
         self.spec = spec
         self.jitted = jitted
         self.compiled: Optional[Callable] = None
+        # the executable's own batch input sharding: a batch arriving
+        # committed elsewhere (host, or the mesh before an elastic
+        # migration) is placed onto it before dispatch
+        self.batch_sharding: Any = None
         self.stats = PhaseStats()
 
 
@@ -1513,9 +1536,14 @@ class DeftRuntime:
                 kw["ag_links"] = ag_links
         if not self.fsdp:
             kw["multi_pod"] = self.multi_pod
+        # keep_unused: a state buffer a phase does not read (an
+        # accumulator it only overwrites) must stay an argument, or its
+        # donation cannot alias the output and the phase allocates a
+        # second copy of it
         return jax.jit(
             functools.partial(step_impl, **kw),
             donate_argnums=(0,) if self.donate else (),
+            keep_unused=True,
         )
 
     def _ensure_entries(
@@ -1666,8 +1694,6 @@ class DeftRuntime:
         into the f32 master; the runtime must have been built with
         ``compute_dtype=dtype`` so the forward casts back down at the
         buffer views."""
-        from jax.sharding import NamedSharding
-
         if self.flat_state and dtype != jnp.float32 \
                 and dtype != self.compute_dtype:
             raise ValueError(
@@ -1682,7 +1708,13 @@ class DeftRuntime:
         dp = self.dp_axes if len(self.dp_axes) > 1 else self.dp_axes[0]
         rep = NamedSharding(self.mesh, P())
         split = NamedSharding(self.mesh, P(dp))
-        acc = init_fused_accumulators(self.layout, self.accum_devices)
+
+        def zeros_at(make, sharding):
+            # zeros are born in their placement: built whole on one
+            # device first, the accumulators of every device (or the
+            # replicated moments plus their copies) can outgrow its HBM
+            return jax.jit(make, out_shardings=sharding)()
+
         if self.flat_state:
             # flat master copy — one buffer per bucket (flatten promotes
             # a low-precision init to f32; a bf16sr master rounds back
@@ -1690,34 +1722,36 @@ class DeftRuntime:
             pbuf = tuple(
                 flatten_buckets(self.layout, jax.tree_util.tree_leaves(params))
             )
+            del params
             if self.master_dtype == "bf16sr":
                 pbuf = tuple(
                     self._round_master(p, b) for b, p in enumerate(pbuf)
                 )
-            opt = init_flat_opt_state(self.opt_spec, self.layout.buf_sizes)
             # sharded engine: commit buffers split over 'data' so every
             # device materializes only its 1/N span
             buf = NamedSharding(self.mesh, P("data")) if self.fsdp else rep
-            opt_shardings = jax.tree.map(
-                lambda x: rep if x.ndim == 0 else buf, opt
-            )
-            state = {
-                "pbuf": jax.device_put(pbuf, buf),
-                "opt": jax.tree.map(jax.device_put, opt, opt_shardings),
-                "cur": jax.device_put(acc["cur"], split),
-                "fut": jax.device_put(acc["fut"], split),
-            }
+            state = {"pbuf": jax.device_put(pbuf, buf)}
+            del pbuf
+            make_opt = functools.partial(
+                init_flat_opt_state, self.opt_spec, self.layout.buf_sizes)
+            state["opt"] = zeros_at(make_opt, jax.tree.map(
+                lambda x: rep if x.ndim == 0 else buf,
+                jax.eval_shape(make_opt)))
+            state.update(zeros_at(functools.partial(
+                init_fused_accumulators, self.layout, self.accum_devices),
+                split))
             if self._gather_skip:
                 state["pgather"] = jax.device_put(
                     self._init_pgather(self.layout), rep
                 )
             return state
-        return {
+        state = {
             "params": jax.device_put(params, rep),
             "opt": jax.device_put(init_opt_state(self.opt_spec, params), rep),
-            "cur": jax.device_put(acc["cur"], split),
-            "fut": jax.device_put(acc["fut"], split),
         }
+        state.update(zeros_at(functools.partial(
+            init_fused_accumulators, self.layout, self.accum_devices), split))
+        return state
 
     def _round_master(self, buf: jax.Array, b: int) -> jax.Array:
         """One flat f32 buffer rounded into the bf16sr resident master
@@ -1743,9 +1777,12 @@ class DeftRuntime:
         / debug points."""
         if not self.flat_state:
             return state["params"]
-        return jax.tree_util.tree_unflatten(
-            self._treedef, unflatten_buckets(self.layout, state["pbuf"])
-        )
+        # under this runtime's own mesh: the caller's ambient mesh may be
+        # another one (an elastic migration moved the state)
+        with jax.set_mesh(self.mesh):
+            return jax.tree_util.tree_unflatten(
+                self._treedef, unflatten_buckets(self.layout, state["pbuf"])
+            )
 
     def state_to_tree(self, state: TrainState) -> TrainState:
         """Checkpoint-friendly tree form {params, opt{step,m[,v]}} of a
@@ -1760,10 +1797,11 @@ class DeftRuntime:
         unflat = lambda bufs: jax.tree_util.tree_unflatten(
             self._treedef, unflatten_buckets(self.layout, bufs)
         )
-        opt: Dict[str, Any] = {"step": state["opt"]["step"],
-                               "m": unflat(state["opt"]["m"])}
-        if "v" in state["opt"]:
-            opt["v"] = unflat(state["opt"]["v"])
+        with jax.set_mesh(self.mesh):
+            opt: Dict[str, Any] = {"step": state["opt"]["step"],
+                                   "m": unflat(state["opt"]["m"])}
+            if "v" in state["opt"]:
+                opt["v"] = unflat(state["opt"]["v"])
         out = {"params": self.params_tree(state), "opt": opt,
                "cur": state["cur"], "fut": state["fut"]}
         if "pgather" in state:
@@ -1866,20 +1904,28 @@ class DeftRuntime:
     def _compile_entries(
         self, entries: Sequence[_PhaseEntry], state, batch
     ) -> Dict[str, float]:
-        out: Dict[str, float] = {}
+        # lowering traces Python and runs one phase at a time; the XLA
+        # compiles release the GIL and run side by side
+        todo = []
         with jax.set_mesh(self.mesh):
             for i, entry in enumerate(entries):
                 if entry.compiled is not None:
                     continue
                 t0 = time.perf_counter()
                 lowered = entry.jitted.lower(state, batch)
-                t1 = time.perf_counter()
-                entry.compiled = lowered.compile()
-                t2 = time.perf_counter()
-                entry.stats.lower_s = t1 - t0
-                entry.stats.compile_s = t2 - t1
-                out[f"phase{i}"] = t2 - t0
-        return out
+                entry.stats.lower_s = time.perf_counter() - t0
+                todo.append((i, entry, lowered))
+
+        def compile_one(item):
+            i, entry, lowered = item
+            t0 = time.perf_counter()
+            entry.compiled = lowered.compile()
+            entry.batch_sharding = entry.compiled.input_shardings[0][1]
+            entry.stats.compile_s = time.perf_counter() - t0
+            return f"phase{i}", entry.stats.lower_s + entry.stats.compile_s
+
+        with concurrent.futures.ThreadPoolExecutor(max(len(todo), 1)) as pool:
+            return dict(pool.map(compile_one, todo))
 
     def compile(self, state: TrainState, batch) -> Dict[str, float]:
         """Lower + compile every unique phase of the installed schedule
@@ -1925,8 +1971,6 @@ class DeftRuntime:
         hit = self._repack_cache.get(transition)
         if hit is not None:
             return hit
-        from jax.sharding import NamedSharding
-
         dst = transition.dst
         dp = self.dp_axes if len(self.dp_axes) > 1 else self.dp_axes[0]
         place = self._state_placement(
@@ -2014,8 +2058,6 @@ class DeftRuntime:
         """Abstract post-repack train state under ``layout`` — what the
         staged schedule's fresh phases are compiled against while the old
         cycle keeps training on the old layout."""
-        from jax.sharding import NamedSharding
-
         dp = self.dp_axes if len(self.dp_axes) > 1 else self.dp_axes[0]
         place = self._state_placement(
             NamedSharding(self.mesh, P()),
@@ -2366,6 +2408,7 @@ class DeftRuntime:
         clock = self.tracer.now if tracing else time.perf_counter
         t0 = clock()
         if entry.compiled is not None:
+            batch = jax.device_put(batch, entry.batch_sharding)
             out = entry.compiled(state, batch)
         else:  # compile() skipped — trace under the mesh on first hit
             with jax.set_mesh(self.mesh):
@@ -2406,6 +2449,18 @@ class DeftRuntime:
         return out
 
     # ---- reporting ------------------------------------------------------
+    def phase_kernels(self) -> List[Dict[str, Any]]:
+        """Per AOT-compiled unique phase: whether it applies the update
+        (``do_update``) and the Pallas kernels its executable calls
+        (``kernels``), read from the compiled HLO, so a kernel that was
+        not taken is absent rather than assumed."""
+        return [
+            {"do_update": e.spec.do_update,
+             "kernels": sorted(set(_TPU_KERNEL_RE.findall(
+                 e.compiled.as_text())))}
+            for e in self._unique_entries if e.compiled is not None
+        ]
+
     def collectives_per_phase(self) -> List[Dict[str, int]]:
         """Static per-schedule-phase collective counts (fused path)."""
         return [phase_collectives(p) for p in self.schedule.phases]

@@ -204,21 +204,16 @@ def _sync_secondary(
 
     Without a chain: hierarchical reduce-scatter over the innermost DP
     axis, all-reduce over the outer (pod/DCN) axes, then all-gather.
-    Falls back to a plain psum when the leading dim does not tile, or
-    when the installed jaxlib cannot partition tiled collectives inside a
-    partial-manual region (see jax_compat.HIERARCHICAL_COLLECTIVES_OK —
-    the all-reduce is numerically identical, only the link shaping is
+    Falls back to a plain psum when the leading dim does not tile (the
+    all-reduce is numerically identical, only the link shaping is
     lost)."""
-    from repro.util.jax_compat import HIERARCHICAL_COLLECTIVES_OK
-
     fast = dp_axes[-1]
     size = dp_sizes[fast]
     if chain is not None and len(dp_axes) == 1 and len(chain) == size:
         from repro.train.chains import chain_all_reduce
 
         return chain_all_reduce(x, fast, chain)
-    if (HIERARCHICAL_COLLECTIVES_OK and x.ndim >= 1
-            and x.shape[0] % size == 0 and x.shape[0] >= size):
+    if x.ndim >= 1 and x.shape[0] % size == 0 and x.shape[0] >= size:
         y = jax.lax.psum_scatter(x, fast, scatter_dimension=0, tiled=True)
         if len(dp_axes) > 1:
             y = jax.lax.psum(y, dp_axes[:-1])

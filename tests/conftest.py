@@ -9,8 +9,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import pytest
 
-import repro  # noqa: F401  (activates the jax version-compat shims)
-
 jax.config.update("jax_enable_x64", False)
 
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _jaxpr_census import count_eqns
 from repro.data.pipeline import make_batch
 from repro.kernels.bucket_update import (
     apply_bucket_updates,
@@ -336,26 +337,6 @@ def test_flat_runtime_staleness_and_no_buffer_growth(single_mesh):
         )
 
 
-def _count_eqns(jaxpr):
-    n = len(jaxpr.eqns)
-    for eqn in jaxpr.eqns:
-        for p in eqn.params.values():
-            for sub in _subjaxprs(p):
-                n += _count_eqns(sub)
-    return n
-
-
-def _subjaxprs(p):
-    core = jax.core
-    if isinstance(p, core.ClosedJaxpr):
-        return [p.jaxpr]
-    if isinstance(p, core.Jaxpr):
-        return [p]
-    if isinstance(p, (list, tuple)):
-        return [j for x in p for j in _subjaxprs(x)]
-    return []
-
-
 def test_flat_update_removes_per_leaf_op_sequence():
     """THE structural claim of the flat engine, asserted the same way
     the runtime asserts its collectives guarantee — by jaxpr
@@ -379,11 +360,11 @@ def test_flat_update_removes_per_leaf_op_sequence():
     opt_f = init_flat_opt_state(spec, layout.buf_sizes)
     opt_l = init_opt_state(spec, tree)
 
-    n_flat = _count_eqns(jax.make_jaxpr(
+    n_flat = count_eqns(jax.make_jaxpr(
         lambda p, g, o: apply_bucket_updates(spec, seg, p, g, o,
                                              grad_scale=0.1)[:2]
     )(pbuf, gbuf, opt_f).jaxpr)
-    n_leaf = _count_eqns(jax.make_jaxpr(
+    n_leaf = count_eqns(jax.make_jaxpr(
         lambda p, g, o: apply_updates(spec, p, g, o, grad_scale=0.1)
     )(tree, grads, opt_l).jaxpr)
     # per-leaf grows ~10 ops/leaf; fused grows ~10 ops/bucket

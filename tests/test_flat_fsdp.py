@@ -354,7 +354,7 @@ def test_sharded_update_is_o_buckets_not_o_leaves():
     per-leaf update over the equivalent 1/N state grows with the leaf
     count.  Wall clock on CPU is load-noisy; this is deterministic."""
     from repro.optim.optimizers import apply_updates, init_opt_state
-    from test_bucket_update import _count_eqns
+    from _jaxpr_census import count_eqns
 
     n_leaves, leaf_elems, n_buckets, n_shards = 64, 512, 4, 4
     key = jax.random.PRNGKey(5)
@@ -376,7 +376,7 @@ def test_sharded_update_is_o_buckets_not_o_leaves():
     o_s = {"step": opt_f["step"], "m": _shard_state(layout, opt_f["m"], 0),
            "v": _shard_state(layout, opt_f["v"], 0)}
 
-    n_flat = _count_eqns(jax.make_jaxpr(
+    n_flat = count_eqns(jax.make_jaxpr(
         lambda p, g, o, i: apply_bucket_updates(
             spec, seg, p, g, o, grad_scale=0.1, shard_id=i,
             norm_psum=lambda t: t)[:2]
@@ -387,7 +387,7 @@ def test_sharded_update_is_o_buckets_not_o_leaves():
     shard_tree = jax.tree.map(lambda x: x[: x.size // n_shards], tree)
     shard_grads = jax.tree.map(lambda x: x[: x.size // n_shards], grads)
     opt_l = init_opt_state(spec, shard_tree)
-    n_leaf = _count_eqns(jax.make_jaxpr(
+    n_leaf = count_eqns(jax.make_jaxpr(
         lambda p, g, o: apply_updates(spec, p, g, o, grad_scale=0.1)
     )(shard_tree, shard_grads, opt_l).jaxpr)
 
@@ -582,29 +582,22 @@ with mesh:
     for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(state)):
         assert bool(jnp.array_equal(a, b)), "sharded roundtrip not exact"
 
-# tree-state RS reference (flat_state=False, XLA-auto FSDP): the
-# partial-manual + FSDP-constraint graph aborts on jaxlib < 0.5
-# (DESIGN.md par.6), so the comparison runs on jax >= 0.5 only
-_v = tuple(int(x) for x in jax.__version__.split(".")[:2])
-if _v >= (0, 5):
-    with mesh:
-        rt_tree = DeftRuntime(cfg, opt, sched, lay_rep, mesh, fsdp=True,
-                              flat_state=False)
-        state_t = rt_tree.init_state(key)
-        state_s = rt.init_state(key)
-        for step in range(sched.period + 1):
-            batch = make_batch(cfg, 0, step, B, S)
-            state_t, _ = rt_tree.step(step, state_t, batch)
-            state_s, _ = rt.step(step, state_s, batch)
-        diff = max(
-            float(jnp.max(jnp.abs(a - b)))
-            for a, b in zip(jax.tree.leaves(rt.params_tree(state_s)),
-                            jax.tree.leaves(rt_tree.params_tree(state_t))))
-        assert diff < 1e-5, f"sharded vs tree-RS reference: {diff}"
-        print(f"TREE_RS_COMPARED diff={diff:.2e}")
-else:
-    print("tree-RS comparison skipped (jaxlib partial-manual CHECK, "
-          f"jax {jax.__version__})")
+# tree-state RS reference (flat_state=False, XLA-auto FSDP)
+with mesh:
+    rt_tree = DeftRuntime(cfg, opt, sched, lay_rep, mesh, fsdp=True,
+                          flat_state=False)
+    state_t = rt_tree.init_state(key)
+    state_s = rt.init_state(key)
+    for step in range(sched.period + 1):
+        batch = make_batch(cfg, 0, step, B, S)
+        state_t, _ = rt_tree.step(step, state_t, batch)
+        state_s, _ = rt.step(step, state_s, batch)
+    diff = max(
+        float(jnp.max(jnp.abs(a - b)))
+        for a, b in zip(jax.tree.leaves(rt.params_tree(state_s)),
+                        jax.tree.leaves(rt_tree.params_tree(state_t))))
+    assert diff < 1e-5, f"sharded vs tree-RS reference: {diff}"
+    print(f"TREE_RS_COMPARED diff={diff:.2e}")
 print("FLAT_FSDP_OK")
 """
 

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.flash import flash_global, flash_local
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_reference
@@ -42,18 +43,73 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 )
 def test_pallas_flash_vs_ref(sq, h, kvh, d, causal, window, softcap, dtype):
     q, k, v = _qkv(jax.random.PRNGKey(0), 2, sq, sq, h, kvh, d, dtype)
-    got = flash_attention_pallas(
+    got, lse = flash_attention_pallas(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3),
         causal=causal, window=window, softcap=softcap,
         block_q=64, block_kv=64, interpret=True,
-    ).transpose(0, 2, 1, 3)
+    )
+    got = got.transpose(0, 2, 1, 3)
     want = attention_reference(q, k, v, causal=causal, window=window,
                                softcap=softcap)
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32),
         atol=TOL[dtype], rtol=TOL[dtype],
     )
+    np.testing.assert_allclose(
+        lse, _reference_lse(q, k, causal=causal, window=window,
+                            softcap=softcap),
+        atol=1e-4, rtol=1e-5,
+    )
+
+
+def _reference_lse(q, k, *, causal, window, softcap):
+    """Row log-sum-exp of the masked scaled scores, [B, H, Sq]."""
+    b, sq, h, d = q.shape
+    kf = jnp.repeat(k.astype(jnp.float32), h // k.shape[2], axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kf) / np.sqrt(d)
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    qpos, kpos = jnp.arange(sq)[:, None], jnp.arange(k.shape[1])[None, :]
+    mask = jnp.ones((sq, k.shape[1]), bool)
+    if causal:
+        mask &= kpos <= qpos
+    if window:
+        mask &= kpos > qpos - window
+    return jax.nn.logsumexp(jnp.where(mask, s, -1e30), axis=-1)
+
+
+# ---------------------------------------------------------------------------
+# Pallas path of flash_attention: custom VJP (interpret mode) vs oracle
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize(
+    "sq,h,kvh,d,window,softcap",
+    [
+        (128, 4, 2, 32, 0, 0.0),       # causal GQA
+        (128, 4, 1, 32, 0, 30.0),      # causal GQA + softcap
+        (128, 4, 2, 32, 48, 0.0),      # sliding window + GQA
+    ],
+)
+def test_pallas_attention_grad_matches_reference(sq, h, kvh, d, window,
+                                                  softcap):
+    q, k, v = _qkv(jax.random.PRNGKey(3), 2, sq, sq, h, kvh, d, jnp.float32)
+    f_new = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+        q, k, v, causal=True, window=window, softcap=softcap,
+        impl="pallas", block_q=64, block_kv=64, interpret=True)))
+    f_ref = lambda q, k, v: jnp.sum(jnp.sin(attention_reference(
+        q, k, v, causal=True, window=window, softcap=softcap)))
+    np.testing.assert_allclose(f_new(q, k, v), f_ref(q, k, v), rtol=1e-5)
+    g_new = jax.grad(f_new, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_new, g_ref):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+def test_pallas_attention_rejects_q_offset():
+    q, k, v = _qkv(jax.random.PRNGKey(4), 1, 64, 64, 2, 2, 32, jnp.float32)
+    with pytest.raises(ValueError, match="q_offset"):
+        flash_attention(q, k, v, causal=True, q_offset=8, impl="pallas",
+                        block_q=64, block_kv=64, interpret=True)
 
 
 # ---------------------------------------------------------------------------

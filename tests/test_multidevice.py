@@ -25,12 +25,7 @@ from repro.optim.optimizers import adamw, apply_updates, init_opt_state
 from repro.train import (DeftRuntime, assign_buckets, build_bucket_layout,
                          init_train_state, leaf_bucket_times)
 
-# jaxlib < 0.5 hard-CHECKs (hlo_sharding_util.cc IsManualSubgroup) when a
-# partial-manual region carries real tensor-parallel constraints on the
-# auto axis; a size-1 model axis keeps the partitioner out of the buggy
-# path while still exercising true 4-way data-parallel collectives.
-_v = tuple(int(x) for x in jax.__version__.split(".")[:2])
-mesh = jax.make_mesh((4, 2) if _v >= (0, 5) else (4, 1), ("data", "model"),
+mesh = jax.make_mesh((4, 2), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = reduce_for_smoke(get_config("qwen3-4b"))
 opt = adamw(1e-3)
@@ -88,41 +83,32 @@ with mesh:
                                    jax.tree.leaves(ref_params)))
         assert diff < 1e-4, f"step {step}: diverged by {diff}"
 
-# ---- DeFT-RS (manual over 'pod', FSDP arch) lowers + runs at small scale.
-# jaxlib < 0.5 aborts with an XLA SPMD CHECK (hlo_sharding_util.cc
-# IsManualSubgroup) on ANY partial-manual + FSDP-constraint graph — an
-# upstream partitioner bug, so the section is gated on the jax version
-# (the 512-device production lowering hits a similar CHECK — upstream). --
-_v = tuple(int(x) for x in jax.__version__.split(".")[:2])
-if _v >= (0, 5):
-    mesh_rs = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                            axis_types=(jax.sharding.AxisType.Auto,) * 3)
-    cfg_rs = reduce_for_smoke(get_config("deepseek-v2-236b"))
-    probe_rs = init_train_state(jax.random.PRNGKey(5), cfg_rs, opt)
-    bo_rs, nb_rs = assign_buckets(probe_rs["params"], cfg_rs,
-                                  partition_elems=150_000)
-    t_rs = leaf_bucket_times(probe_rs["params"], cfg_rs, bo_rs, nb_rs,
-                             HardwareModel(dp_degree=2), 32, 4)
-    t_rs = BucketTimes(t_rs.fwd, t_rs.bwd,
-                       tuple(c * 50 for c in t_rs.comm))
-    sched_rs = solve_schedule(t_rs, SchedulerConfig())
-    # sharded flat engine (the fsdp default): layout split into 2 spans
-    # to match the mesh's 2-way 'data' axis
-    lay_rs = build_bucket_layout(probe_rs["params"], bo_rs, nb_rs,
-                                 shard_count=2)
-    with mesh_rs:
-        rt_rs = DeftRuntime(cfg_rs, opt, sched_rs, lay_rs, mesh_rs, fsdp=True)
-        assert rt_rs.flat_state, "fsdp now defaults to the sharded engine"
-        state_rs = rt_rs.init_state(jax.random.PRNGKey(5))
-        for step in range(min(sched_rs.period + 1, 4)):
-            b_rs = make_batch(cfg_rs, 0, step, 8, 32)
-            state_rs, m_rs = rt_rs.step(step, state_rs, b_rs)
-            assert jnp.isfinite(m_rs["loss"])
-    # the tree-state RS path (flat_state=False) stays available and is
-    # exercised against the flat engine in test_flat_fsdp.py
-else:
-    print("RS section skipped: jaxlib SPMD partial-manual CHECK bug "
-          f"(jax {jax.__version__})")
+# ---- DeFT-RS (manual over 'pod', FSDP arch) lowers + runs at small scale --
+mesh_rs = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 3)
+cfg_rs = reduce_for_smoke(get_config("deepseek-v2-236b"))
+probe_rs = init_train_state(jax.random.PRNGKey(5), cfg_rs, opt)
+bo_rs, nb_rs = assign_buckets(probe_rs["params"], cfg_rs,
+                              partition_elems=150_000)
+t_rs = leaf_bucket_times(probe_rs["params"], cfg_rs, bo_rs, nb_rs,
+                         HardwareModel(dp_degree=2), 32, 4)
+t_rs = BucketTimes(t_rs.fwd, t_rs.bwd,
+                   tuple(c * 50 for c in t_rs.comm))
+sched_rs = solve_schedule(t_rs, SchedulerConfig())
+# sharded flat engine (the fsdp default): layout split into 2 spans
+# to match the mesh's 2-way 'data' axis
+lay_rs = build_bucket_layout(probe_rs["params"], bo_rs, nb_rs,
+                             shard_count=2)
+with mesh_rs:
+    rt_rs = DeftRuntime(cfg_rs, opt, sched_rs, lay_rs, mesh_rs, fsdp=True)
+    assert rt_rs.flat_state, "fsdp now defaults to the sharded engine"
+    state_rs = rt_rs.init_state(jax.random.PRNGKey(5))
+    for step in range(min(sched_rs.period + 1, 4)):
+        b_rs = make_batch(cfg_rs, 0, step, 8, 32)
+        state_rs, m_rs = rt_rs.step(step, state_rs, b_rs)
+        assert jnp.isfinite(m_rs["loss"])
+# the tree-state RS path (flat_state=False) stays available and is
+# exercised against the flat engine in test_flat_fsdp.py
 
 # ---- sharded flash-decode (distributed softmax) vs oracle ----
 import numpy as np
@@ -147,17 +133,73 @@ print("MULTIDEVICE_OK")
 """
 
 
-@pytest.mark.slow
-@pytest.mark.multidevice
-def test_deft_equivalence_on_8_devices(tmp_path):
+def _run(tmp_path, script_text: str, timeout: int) -> str:
     src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
     script = tmp_path / "run.py"
-    script.write_text(_SCRIPT)
+    script.write_text(script_text)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, str(script), src],
-        capture_output=True, text=True, timeout=900, env=env,
+        capture_output=True, text=True, timeout=timeout, env=env,
     )
     assert out.returncode == 0, out.stderr[-4000:]
-    assert "MULTIDEVICE_OK" in out.stdout
+    return out.stdout
+
+
+@pytest.mark.slow
+@pytest.mark.multidevice
+def test_deft_equivalence_on_8_devices(tmp_path):
+    assert "MULTIDEVICE_OK" in _run(tmp_path, _SCRIPT, 900)
+
+
+# A plain jit over several devices (the DDP step's form) cannot partition
+# a Mosaic kernel: the Pallas attention path runs per shard in a
+# shard_map, batch over 'data', heads over 'model' when q and kv heads
+# both divide it (else replicated).  Interpret mode on forced host
+# devices checks the split arithmetic against the oracle.
+_PALLAS_SHARDED_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, sys.argv[1])
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention.ref import attention_reference
+from repro.sharding import logical_rules, rules_pjit
+
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+for kvh in (2, 1):
+    key = jax.random.PRNGKey(kvh)
+    q = jax.random.normal(key, (4, 128, 4, 32))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (4, 128, kvh, 32))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (4, 128, kvh, 32))
+
+    def f_new(q, k, v):
+        with logical_rules(rules_pjit(False, False)):
+            return jnp.sum(jnp.sin(flash_attention(
+                q, k, v, causal=True, impl="pallas", block_q=64,
+                block_kv=64, interpret=True)))
+
+    f_ref = lambda q, k, v: jnp.sum(jnp.sin(attention_reference(
+        q, k, v, causal=True)))
+    with jax.set_mesh(mesh):
+        placed = jax.device_put((q, k, v),
+                                NamedSharding(mesh, P("data")))
+        step = jax.jit(jax.value_and_grad(f_new, argnums=(0, 1, 2)))
+        assert "shard_map" in str(step.trace(*placed).jaxpr)
+        got, g_new = step(*placed)
+    want, g_ref = jax.value_and_grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(g_new, g_ref):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+print("PALLAS_SHARDED_OK")
+"""
+
+
+@pytest.mark.multidevice
+def test_pallas_attention_runs_per_shard_under_plain_jit(tmp_path):
+    assert "PALLAS_SHARDED_OK" in _run(tmp_path, _PALLAS_SHARDED_SCRIPT, 300)

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
+from _jaxpr_census import count_primitives
 from repro.adapt import (
     AdaptConfig,
     AdaptiveController,
@@ -421,33 +422,12 @@ def test_gather_skip_bitwise_and_fewer_allgathers(single_mesh):
                        if not sched.phases[t - 1].do_update)
         batch = make_batch(cfg, 0, 0, B, S)
 
-        def subjaxprs(val):
-            import jax.core as jc
-
-            if isinstance(val, jc.ClosedJaxpr):
-                yield val.jaxpr
-            elif isinstance(val, jc.Jaxpr):
-                yield val
-            elif isinstance(val, (list, tuple)):
-                for v in val:
-                    yield from subjaxprs(v)
-
-        def count_allgather_eqns(jaxpr):
-            n = 0
-            for eqn in jaxpr.eqns:
-                if eqn.primitive.name == "all_gather":
-                    n += 1
-                for val in eqn.params.values():
-                    for sub in subjaxprs(val):
-                        n += count_allgather_eqns(sub)
-            return n
-
         def count_allgather(rt, state, t):
             key_t = rt._schedule_keys(rt.schedule)[t]
             jaxpr = jax.make_jaxpr(
                 lambda s, bb: rt._entries[key_t].jitted(s, bb)
             )(state, batch)
-            return count_allgather_eqns(jaxpr.jaxpr)
+            return count_primitives(jaxpr.jaxpr, "all_gather")
 
         n_on = count_allgather(rt_on, s_on, reuse_t)
         n_off = count_allgather(rt_off, s_off, reuse_t)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from _jaxpr_census import count_primitives
 from repro.configs import get_config, reduce_for_smoke
 from repro.core.bucket import BucketTimes
 from repro.core.deft import solve_schedule
@@ -163,31 +164,9 @@ _COLLECTIVE_PRIMS = {
 }
 
 
-def _iter_eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for p in eqn.params.values():
-            for sub in _subjaxprs(p):
-                yield from _iter_eqns(sub)
-
-
-def _subjaxprs(p):
-    core = jax.core
-    if isinstance(p, core.ClosedJaxpr):
-        return [p.jaxpr]
-    if isinstance(p, core.Jaxpr):
-        return [p]
-    if isinstance(p, (list, tuple)):
-        return [j for x in p for j in _subjaxprs(x)]
-    return []
-
-
 def _count_collectives(fn, *args):
-    jaxpr = jax.make_jaxpr(fn)(*args)
-    return sum(
-        1 for eqn in _iter_eqns(jaxpr.jaxpr)
-        if eqn.primitive.name in _COLLECTIVE_PRIMS
-    )
+    return count_primitives(jax.make_jaxpr(fn)(*args).jaxpr,
+                            _COLLECTIVE_PRIMS)
 
 
 def test_fused_phase_one_collective_per_synced_bucket(single_mesh):
