@@ -1,0 +1,19 @@
+"""Device time per step of the ops under the named scope
+``flash_attention_bwd_blocked`` (the attention backward), averaged over
+the chips."""
+from bench import trace_reduce as tr
+
+SCOPE = "flash_attention_bwd_blocked"
+
+
+def read(ctx):
+    t, lo, hi, scopes = ctx["trace"], ctx["lo"], ctx["hi"], ctx["scopes"]
+    per_dev = []
+    for d in ctx["devices"]:
+        ops = tr.ops_matching(t, d, lambda o: SCOPE in scopes.get(o.name, ""))
+        if ops:
+            ivs = tr.clip(tr.union((o.start, o.end) for o in ops), lo, hi)
+            per_dev.append(tr.total(ivs))
+    if not per_dev:
+        return None
+    return sum(per_dev) / len(per_dev) * 1e-6 / ctx["steps"]
