@@ -36,7 +36,7 @@ if [[ "$mode" == "--smoke" ]]; then
   python - <<'PY'
 import json
 kinds = {e.get("cat") for e in json.load(open("trace_smoke.json"))["traceEvents"]}
-need = {"step", "phase", "collective-group", "swap-compile",
+need = {"step", "phase", "place", "launch", "swap-compile",
         "swap-install", "replan", "repack"}
 missing = need - kinds
 assert not missing, f"trace_smoke.json missing span kinds: {missing}"
@@ -46,7 +46,7 @@ PY
   # forced int8 wire + bf16sr master -> quantized layout -> traced
   # quantized collectives ('auto' would keep f32 here: the smoke
   # model's us-scale comm sits under the collective latency floor, so
-  # the ladder rightly finds no gain).  The trace must carry per-group
+  # the ladder rightly finds no gain).  The phase spans must carry
   # wire_bytes/precision attrs so the wire-bytes attribution
   # (obs.wire_bytes_report) can close the loop
   python -m repro.launch.train --smoke --scheduler deft --steps 12 \
@@ -55,13 +55,13 @@ PY
   python - <<'PY'
 import json
 evs = json.load(open("trace_precision.json"))["traceEvents"]
-coll = [e for e in evs if e.get("cat") == "collective-group"]
-assert coll, "trace_precision.json has no collective-group spans"
-tagged = [e for e in coll if "wire_bytes" in e.get("args", {})]
-assert tagged, "collective-group spans carry no wire_bytes attrs"
+phases = [e for e in evs if e.get("cat") == "phase"]
+assert phases, "trace_precision.json has no phase spans"
+tagged = [e for e in phases if "wire_bytes" in e.get("args", {})]
+assert tagged, "phase spans carry no wire_bytes attrs"
 prec = {e["args"].get("precision") for e in tagged}
-print(f"trace_precision.json OK ({len(tagged)} quantized collective "
-      f"spans, precisions={sorted(p for p in prec if p)})")
+print(f"trace_precision.json OK ({len(tagged)} phase spans with "
+      f"wire bytes, precisions={sorted(p for p in prec if p)})")
 PY
   echo "verify.sh --smoke: OK"
   exit 0

@@ -587,8 +587,13 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
             )
             del prev
             if tracer is not None:
+                # the blocked step, tagged with the cycle phase it ran:
+                # what obs attribution measures phases by
+                tags = {} if runtime is None else dict(
+                    phase=runtime.last_phase,
+                    first=runtime.last_dispatch_first)
                 tracer.add("step", f"step{step}", t_s, tracer.now(),
-                           step=step)
+                           step=step, **tags)
             if elastic is not None:
                 if scenario is not None:
                     obs = scenario.observe(step, wall)
@@ -685,8 +690,7 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
             st = runtime.stats()
             print(f"adapt: {st['replans']} replans, {st['hot_swaps']} "
                   f"hot-swaps ({st['layout_swaps']} layout-changing), "
-                  f"{st['cached_phases']} cached phases, "
-                  f"{st['steps_per_s']:.2f} steps/s (dispatch)")
+                  f"{st['cached_phases']} cached phases")
             for sw in st["swap_log"]:
                 print("  " + format_event(sw))
             for ev in (controller.events if controller else []):

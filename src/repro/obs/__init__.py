@@ -10,7 +10,9 @@ Three pieces, consumed by every other subsystem:
   own metrics (measured coverage rate, per-bucket bubble time, knapsack
   capacity utilization, predicted-vs-actual divergence per bucket);
 * :mod:`repro.obs.events` — the one formatter every event surface
-  (swap log, replan events, elastic faults/migrations) prints through.
+  (swap log, replan events, elastic faults/migrations) prints through;
+* :mod:`repro.obs.hlo_scopes` — the engine's named scopes found again in
+  a compiled phase, so each collective names its buckets and links.
 """
 from repro.obs.trace import ManualClock, Span, SPAN_KINDS, Tracer
 from repro.obs.metrics import Metrics, METRICS_SCHEMA_VERSION, validate_summary
@@ -31,6 +33,7 @@ from repro.obs.attribution import (
     wire_bytes_report,
 )
 from repro.obs.events import format_event
+from repro.obs.hlo_scopes import collective_scopes
 
 __all__ = [
     "Attribution",
@@ -44,6 +47,7 @@ __all__ = [
     "attribute",
     "attribute_trace",
     "bucket_divergence",
+    "collective_scopes",
     "format_event",
     "latest_phase_durations",
     "measured_phase_durations_from_trace",

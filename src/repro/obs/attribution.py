@@ -436,10 +436,13 @@ def attribute(
 def measured_phase_durations_from_trace(
     tracer: Tracer, period: int
 ) -> List[Optional[float]]:
-    """Mean per-cycle-phase duration of recorded ``phase`` spans,
-    excluding first-dispatch spans (``first`` tag — compile pollution)."""
+    """Mean per-cycle-phase duration of the training loop's blocked ``step``
+    spans (the step's wall until its outputs are ready, tagged with the
+    cycle phase it dispatched), excluding first-dispatch spans (``first``
+    tag — compile pollution).  The runtime's ``phase`` span times only
+    the host enqueue, which on a chip is a small part of the step."""
     acc: Dict[int, List[float]] = {}
-    for sp in tracer.spans("phase"):
+    for sp in tracer.spans("step"):
         if sp.phase is None or not 0 <= sp.phase < period:
             continue
         if sp.args.get("first"):
@@ -457,7 +460,8 @@ def attribute_trace(
     scfg: SchedulerConfig,
     schedule: DeftSchedule,
 ) -> Attribution:
-    """:func:`attribute` over the ``phase`` spans in a live trace."""
+    """:func:`attribute` over the blocked ``step`` spans in a live
+    trace."""
     measured = measured_phase_durations_from_trace(tracer, schedule.period)
     return attribute(measured, times, scfg, schedule)
 
@@ -473,7 +477,8 @@ class WireBytesReport:
     ``planned_per_phase`` is what the *current* plan prices (layout
     precision applied to each phase's synced buckets);
     ``measured_per_phase`` is what the executed collectives actually
-    shipped, read back from the runtime's ``collective-group`` spans.
+    shipped, read back from the attributes of the runtime's ``phase``
+    spans.
     The two diverge exactly when execution lags the plan — e.g. steps
     that ran on a stale layout while a precision hot-swap compiled —
     so ``ok`` is the end-to-end check that the policy the knapsack
@@ -538,13 +543,13 @@ class WireBytesReport:
 def wire_bytes_from_trace(
     tracer: Tracer, period: int
 ) -> Tuple[List[Optional[float]], List[Optional[str]]]:
-    """Mean ``wire_bytes`` (and the wire tag) of the recorded
-    ``collective-group`` spans per cycle phase.  First-dispatch spans
-    are NOT excluded — byte counts are exact regardless of compile
-    pollution; only durations need the ``first`` filter."""
+    """Mean ``wire_bytes`` (and the wire tag) of the recorded ``phase``
+    spans per cycle phase.  First-dispatch spans are NOT excluded —
+    byte counts are exact regardless of compile pollution; only
+    durations need the ``first`` filter."""
     acc: Dict[int, List[float]] = {}
     tags: Dict[int, str] = {}
-    for sp in tracer.spans("collective-group"):
+    for sp in tracer.spans("phase"):
         if sp.phase is None or not 0 <= sp.phase < period:
             continue
         wb = sp.args.get("wire_bytes")
@@ -564,12 +569,11 @@ def wire_bytes_from_trace(
 def link_wire_bytes_from_trace(
     tracer: Tracer, period: int
 ) -> List[Optional[Tuple[float, float]]]:
-    """Mean (primary, secondary) wire bytes of the recorded
-    ``collective-group`` spans per cycle phase (§14).  ``None`` for
-    phases with no spans or spans from a runtime that predates the
-    per-link attrs."""
+    """Mean (primary, secondary) wire bytes of the recorded ``phase``
+    spans per cycle phase (§14).  ``None`` for phases with no spans or
+    spans without the per-link attrs."""
     acc: Dict[int, List[Tuple[float, float]]] = {}
-    for sp in tracer.spans("collective-group"):
+    for sp in tracer.spans("phase"):
         if sp.phase is None or not 0 <= sp.phase < period:
             continue
         wp = sp.args.get("wire_bytes_primary")

@@ -18,6 +18,9 @@ it fit this codebase:
 
 Span kinds are a closed vocabulary (:data:`SPAN_KINDS`) so the
 attribution pass and the Chrome export can assign stable tracks.
+:meth:`Tracer.span` also opens a ``jax.profiler.TraceAnnotation`` of
+the span's name, so while a profiler session is active the span lands
+on the session's host plane, on one clock with the device trace.
 Export follows the Chrome trace-event format — complete (``"ph": "X"``)
 duration events plus instant (``"ph": "i"``) events, timestamps in
 microseconds — which Perfetto loads directly.
@@ -31,13 +34,17 @@ import time
 from collections import deque
 from typing import Callable, Deque, Dict, Iterable, List, Optional, Sequence, Tuple
 
-#: Closed span-kind vocabulary.  ``step``/``phase`` are the per-step
-#: timing backbone; ``collective-group`` mirrors the fused collectives a
-#: dispatched phase contains; the rest are control-plane events.
+from jax.profiler import TraceAnnotation
+
+#: Closed span-kind vocabulary.  ``step`` (the training loop's blocked step)
+#: is the per-step timing backbone; ``phase`` is the host side of one
+#: ``DeftRuntime.step``, split into ``place`` and ``launch``; the rest
+#: are control-plane events.
 SPAN_KINDS: Tuple[str, ...] = (
     "step",              # one full train-loop step (driver-measured)
-    "phase",             # one DeftRuntime.step dispatch (runtime-measured)
-    "collective-group",  # the collectives fused into a dispatched phase
+    "phase",             # one DeftRuntime.step call on the host
+    "place",             # the batch's device_put inside a phase
+    "launch",            # the executable call inside a phase
     "update-apply",      # optimizer-update positions in the cycle
     "gather-skip",       # phases dispatched with the gather-reuse mask
     "swap-install",      # pending schedule installed at a cycle boundary
@@ -52,13 +59,14 @@ SPAN_KINDS: Tuple[str, ...] = (
 
 #: Default Chrome-export track per kind (pid 0, one tid per track).
 _TRACKS: Tuple[str, ...] = (
-    "steps", "phases", "collectives", "control", "elastic",
+    "steps", "phases", "control", "elastic",
     "sim-compute", "sim-link0", "sim-link1",
 )
 _KIND_TRACK: Dict[str, str] = {
     "step": "steps",
     "phase": "phases",
-    "collective-group": "collectives",
+    "place": "phases",
+    "launch": "phases",
     "update-apply": "phases",
     "gather-skip": "phases",
     "swap-install": "control",
@@ -183,27 +191,46 @@ class Tracer:
             kind, name, at, at, step=step, phase=phase, track=track, **attrs
         )
 
-    @contextlib.contextmanager
     def span(
         self,
         kind: str,
         name: str,
         *,
+        record: bool = True,
         step: Optional[int] = None,
         phase: Optional[int] = None,
         track: Optional[str] = None,
         **attrs: object,
     ):
         """Context manager that measures the enclosed block with the
-        tracer's clock.  The span is recorded even if the block raises."""
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.add(
-                kind, name, t0, self.now(),
-                step=step, phase=phase, track=track, **attrs,
-            )
+        tracer's clock and records it, even if the block raises.
+
+        The block runs under a ``jax.profiler.TraceAnnotation`` named
+        ``name``: while a profiler session is active the span also lands
+        on the session's host plane.  ``record=False`` opens only the
+        annotation — nothing enters the ring and no clock is read, so an
+        inactive annotation's own check is all it costs.  A recorded span
+        yields its attribute dict; attributes known only inside the block
+        (``step`` and ``phase`` among them) are added to it there."""
+        if not record:
+            return TraceAnnotation(name)
+        if kind not in SPAN_KINDS:
+            raise ValueError(f"unknown span kind {kind!r}")
+        return self._recorded(kind, name, step, phase, track, attrs)
+
+    @contextlib.contextmanager
+    def _recorded(self, kind, name, step, phase, track, attrs):
+        with TraceAnnotation(name):
+            t0 = self.now()
+            try:
+                yield attrs
+            finally:
+                step = attrs.pop("step", step)
+                phase = attrs.pop("phase", phase)
+                self.add(
+                    kind, name, t0, self.now(),
+                    step=step, phase=phase, track=track, **attrs,
+                )
 
     # ---- queries --------------------------------------------------------
     def spans(

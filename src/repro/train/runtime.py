@@ -44,7 +44,8 @@ import re
 import threading
 import time
 import warnings
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, FrozenSet, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,7 @@ from repro.kernels.quantize import (
     stochastic_round_bf16,
 )
 from repro.models.model import init_params, loss_fn
+from repro.obs.hlo_scopes import collective_scopes
 from repro.obs.trace import Tracer
 from repro.optim.optimizers import OptimizerSpec, apply_updates, init_opt_state
 from repro.sharding import (
@@ -109,38 +111,66 @@ def init_fused_accumulators(
 # ---------------------------------------------------------------------------
 # Shared per-bucket routing (identical for tree-state and flat-state paths)
 # ---------------------------------------------------------------------------
+def _sync_scope(phase: PhaseSpec, b: int, gen: str):
+    """Named scope of bucket ``b``'s gradient sync of generation ``gen``
+    ('cur': the older one, 'new': this step's): one path component,
+    ``deft_sync.b<b>.<primary|secondary>.<gen>``, so a collective in the
+    compiled program and the device trace names its bucket, its planned
+    link and its generation."""
+    link = "secondary" if phase.secondary[b] else "primary"
+    return jax.named_scope(f"deft_sync.b{b}.{link}.{gen}")
+
+
+def _gather_scope(b: int, secondary: bool):
+    """Named scope of bucket ``b``'s param or trailing all-gather."""
+    link = "secondary" if secondary else "primary"
+    return jax.named_scope(f"deft_gather.b{b}.{link}")
+
+
 def _route_and_sync(phase: PhaseSpec, g_flat, cur, fut, sync):
     """DeFT generation bookkeeping on per-bucket flat buffers.
 
     Returns (gen, new_fut, cur_synced): the merged fresh generation (or
     None when not rotating), the next future accumulator, and the older
-    generation with this phase's scheduled collectives applied.
+    generation with this phase's scheduled collectives applied.  The
+    bookkeeping runs under the scope ``deft_route``, each sync under its
+    own :func:`_sync_scope`.
     """
+    def synced(x, b, gen):
+        with _sync_scope(phase, b, gen):
+            return sync(x, b)
+
     if phase.rotate:
         # fresh generation merges with the future accumulator (Cases 3/4)
-        gen = [g + f for g, f in zip(g_flat, fut)]
+        with jax.named_scope("deft_route"):
+            gen = [g + f for g, f in zip(g_flat, fut)]
         gen = [
-            sync(x, b) if phase.route_new[b] == "sync" else x
+            synced(x, b, "new") if phase.route_new[b] == "sync" else x
             for b, x in enumerate(gen)
         ]
-        new_fut = [jnp.zeros_like(f) for f in fut]
+        with jax.named_scope("deft_route"):
+            new_fut = [jnp.zeros_like(f) for f in fut]
     else:
         # Cases 1/2: fresh gradients accumulate locally
         gen = None
-        new_fut = [f + g for f, g in zip(fut, g_flat)]
+        with jax.named_scope("deft_route"):
+            new_fut = [f + g for f, g in zip(fut, g_flat)]
 
     # older generation buckets scheduled this phase (fwd Case 1 + bwd 2/3)
     cur_synced = [
-        sync(c, b) if phase.sync_cur[b] else c for b, c in enumerate(cur)
+        synced(c, b, "cur") if phase.sync_cur[b] else c
+        for b, c in enumerate(cur)
     ]
     return gen, new_fut, cur_synced
 
 
 def _fused_metrics(loss, parts, phase: PhaseSpec, dp_axes, n_dp: int):
-    """Loss and aux parts ride ONE fused psum, stacked to a vector."""
+    """Loss and aux parts ride ONE fused psum, stacked to a vector,
+    under the scope ``deft_metrics``."""
     part_keys = sorted(parts)
-    stacked = jnp.stack([loss] + [parts[k] for k in part_keys])
-    stacked = jax.lax.psum(stacked, dp_axes) / n_dp
+    with jax.named_scope("deft_metrics"):
+        stacked = jnp.stack([loss] + [parts[k] for k in part_keys])
+        stacked = jax.lax.psum(stacked, dp_axes) / n_dp
     return {
         "loss": stacked[0],
         **{k: stacked[1 + j] for j, k in enumerate(part_keys)},
@@ -243,7 +273,7 @@ def _deft_body_fused(
     for a in dp_axes:
         n_dp *= dp_sizes[a]
     params, opt = state["params"], state["opt"]
-    with logical_rules(rules):
+    with jax.named_scope("deft_model"), logical_rules(rules):
         (loss, parts), grads = jax.value_and_grad(
             lambda p: loss_fn(p, cfg, batch, remat=remat,
                               loss_chunk=loss_chunk, unroll=unroll),
@@ -251,7 +281,8 @@ def _deft_body_fused(
         )(params)
 
     g_leaves, treedef = jax.tree_util.tree_flatten(grads)
-    g_flat = flatten_buckets(layout, g_leaves)         # one buffer per bucket
+    with jax.named_scope("deft_grads"):
+        g_flat = flatten_buckets(layout, g_leaves)     # one buffer per bucket
     cur = [c[0] for c in state["cur"]]
     fut = [f[0] for f in state["fut"]]
 
@@ -265,18 +296,18 @@ def _deft_body_fused(
 
     if phase.do_update:
         src = cur_synced if phase.update_source == "cur" else gen
-        grad_tree = jax.tree_util.tree_unflatten(
-            treedef, unflatten_buckets(layout, src)
-        )
         scale = 1.0 / (n_dp * phase.update_k)
-        params, opt = apply_updates(opt_spec, params, grad_tree, opt,
-                                    grad_scale=scale)
-        if phase.update_source == "cur":
-            new_cur = gen if gen is not None else [
-                jnp.zeros_like(c) for c in cur_synced
-            ]
-        else:
-            new_cur = [jnp.zeros_like(c) for c in cur_synced]
+        with jax.named_scope("deft_update"):
+            grad_tree = jax.tree_util.tree_unflatten(
+                treedef, unflatten_buckets(layout, src)
+            )
+            params, opt = apply_updates(opt_spec, params, grad_tree, opt,
+                                        grad_scale=scale)
+        with jax.named_scope("deft_route"):
+            if phase.update_source == "cur" and gen is not None:
+                new_cur = gen
+            else:
+                new_cur = [jnp.zeros_like(c) for c in cur_synced]
     elif phase.rotate:
         new_cur = gen
     else:
@@ -329,18 +360,20 @@ def _deft_body_flat(
     for a in dp_axes:
         n_dp *= dp_sizes[a]
     pbuf, opt = state["pbuf"], state["opt"]
-    params = jax.tree_util.tree_unflatten(
-        treedef, unflatten_buckets(layout, pbuf)
-    )
-    params = _cast_compute(params, compute_dtype)
-    with logical_rules(rules):
-        (loss, parts), grads = jax.value_and_grad(
-            lambda p: loss_fn(p, cfg, batch, remat=remat,
-                              loss_chunk=loss_chunk, unroll=unroll),
-            has_aux=True,
-        )(params)
+    with jax.named_scope("deft_model"):
+        params = jax.tree_util.tree_unflatten(
+            treedef, unflatten_buckets(layout, pbuf)
+        )
+        params = _cast_compute(params, compute_dtype)
+        with logical_rules(rules):
+            (loss, parts), grads = jax.value_and_grad(
+                lambda p: loss_fn(p, cfg, batch, remat=remat,
+                                  loss_chunk=loss_chunk, unroll=unroll),
+                has_aux=True,
+            )(params)
 
-    g_flat = flatten_buckets(layout, jax.tree_util.tree_leaves(grads))
+    with jax.named_scope("deft_grads"):
+        g_flat = flatten_buckets(layout, jax.tree_util.tree_leaves(grads))
     cur = [c[0] for c in state["cur"]]
     fut = [f[0] for f in state["fut"]]
     wire = _layout_wire(layout)
@@ -361,11 +394,12 @@ def _deft_body_flat(
         # (rotate) or comes back zeroed fused from the update launch
         zero_grads = (phase.update_source == "new") or (gen is None)
         scale = 1.0 / (n_dp * phase.update_k)
-        pbuf, opt, zeroed = apply_bucket_updates(
-            opt_spec, segments, pbuf, src, opt,
-            grad_scale=scale, zero_grads=zero_grads, impl=update_impl,
-            master_dtype=master_dtype,
-        )
+        with jax.named_scope("deft_update"):
+            pbuf, opt, zeroed = apply_bucket_updates(
+                opt_spec, segments, pbuf, src, opt,
+                grad_scale=scale, zero_grads=zero_grads, impl=update_impl,
+                master_dtype=master_dtype,
+            )
         if phase.update_source == "cur" and gen is not None:
             new_cur = gen
         else:
@@ -481,9 +515,14 @@ def _deft_body_flat_rs(
     )
     ag_ = lambda x: jax.lax.all_gather(x, shard_axis, axis=0, tiled=True)
     ag_chain = lambda x: chain_all_gather(x, shard_axis, secondary_chain)
-    gather_bucket = lambda b: _wire_gather(
-        pbuf_sh[b], wire[b], ag_chain if chained(b) else ag_, fwd_dtype
-    )
+
+    def gather_bucket(b: int) -> jax.Array:
+        with _gather_scope(b, bool(ag_links and ag_links[b])):
+            return _wire_gather(
+                pbuf_sh[b], wire[b], ag_chain if chained(b) else ag_,
+                fwd_dtype,
+            )
+
     cache = state.get("pgather")
     reuse = gather_reuse if (cache is not None and gather_reuse) \
         else (False,) * layout.n_buckets
@@ -524,28 +563,32 @@ def _deft_body_flat_rs(
                 full_buf(b)
             return loss, (parts, tuple(gathered[b] for b in range(nb_)))
 
-        with logical_rules(rules):
+        with jax.named_scope("deft_model"), logical_rules(rules):
             (loss, (parts, pbuf_t)), gz = jax.value_and_grad(
                 run, has_aux=True
             )(zbufs)
         pbuf = list(pbuf_t)
-        g_flat = [g.astype(jnp.float32) for g in gz]
+        with jax.named_scope("deft_grads"):
+            g_flat = [g.astype(jnp.float32) for g in gz]
     else:
         pbuf = [
             cache[b] if reuse[b] else gather_bucket(b)
             for b in range(nb_)
         ]
-        params = jax.tree_util.tree_unflatten(
-            treedef, unflatten_buckets(layout, pbuf)
-        )
-        with logical_rules(rules):
-            (loss, parts), grads = jax.value_and_grad(
-                lambda p: loss_fn(p, cfg, batch, remat=remat,
-                                  loss_chunk=loss_chunk, unroll=unroll),
-                has_aux=True,
-            )(params)
+        with jax.named_scope("deft_model"):
+            params = jax.tree_util.tree_unflatten(
+                treedef, unflatten_buckets(layout, pbuf)
+            )
+            with logical_rules(rules):
+                (loss, parts), grads = jax.value_and_grad(
+                    lambda p: loss_fn(p, cfg, batch, remat=remat,
+                                      loss_chunk=loss_chunk, unroll=unroll),
+                    has_aux=True,
+                )(params)
 
-        g_flat = flatten_buckets(layout, jax.tree_util.tree_leaves(grads))
+        with jax.named_scope("deft_grads"):
+            g_flat = flatten_buckets(
+                layout, jax.tree_util.tree_leaves(grads))
     cur = [c[0] for c in state["cur"]]
     fut = [f[0] for f in state["fut"]]
 
@@ -574,9 +617,10 @@ def _deft_body_flat_rs(
     def gather(y: jax.Array, b: int) -> jax.Array:
         """Trailing all-gather of a synced-and-stored bucket — on the
         same link its reduce-scatter used."""
-        if secondary_chain is not None and phase.secondary[b]:
-            return chain_all_gather(y, shard_axis, secondary_chain)
-        return jax.lax.all_gather(y, shard_axis, axis=0, tiled=True)
+        with _gather_scope(b, phase.secondary[b]):
+            if secondary_chain is not None and phase.secondary[b]:
+                return chain_all_gather(y, shard_axis, secondary_chain)
+            return jax.lax.all_gather(y, shard_axis, axis=0, tiled=True)
 
     def slice_shard(x: jax.Array, b: int) -> jax.Array:
         """This device's span of an already-summed full buffer."""
@@ -591,24 +635,29 @@ def _deft_body_flat_rs(
     gen_sh: List[Optional[jax.Array]] = [None] * nb
     cur_sh: List[Optional[jax.Array]] = [None] * nb
     if phase.rotate:
-        gen_pre = [g + f for g, f in zip(g_flat, fut)]
+        with jax.named_scope("deft_route"):
+            gen_pre = [g + f for g, f in zip(g_flat, fut)]
         gen = []
         for b, x in enumerate(gen_pre):
             if phase.route_new[b] == "sync":
-                gen_sh[b] = rs_shard(x, b)
+                with _sync_scope(phase, b, "new"):
+                    gen_sh[b] = rs_shard(x, b)
                 # stored full only when this generation survives the
                 # phase (it becomes new_cur); a consumed one stays 1/N
                 gen.append(x if consumed_new else gather(gen_sh[b], b))
             else:
                 gen.append(x)
-        new_fut = [jnp.zeros_like(f) for f in fut]
+        with jax.named_scope("deft_route"):
+            new_fut = [jnp.zeros_like(f) for f in fut]
     else:
         gen = None
-        new_fut = [f + g for f, g in zip(fut, g_flat)]
+        with jax.named_scope("deft_route"):
+            new_fut = [f + g for f, g in zip(fut, g_flat)]
     cur_synced = []
     for b, c in enumerate(cur):
         if phase.sync_cur[b]:
-            cur_sh[b] = rs_shard(c, b)
+            with _sync_scope(phase, b, "cur"):
+                cur_sh[b] = rs_shard(c, b)
             cur_synced.append(c if consumed_cur else gather(cur_sh[b], b))
         else:
             cur_synced.append(c)
@@ -619,24 +668,27 @@ def _deft_body_flat_rs(
         # shard-local merged gradient: the fresh reduce-scatter result
         # where this phase synced the bucket, else this device's span of
         # the stored (already-summed) accumulator
-        src_sh = [
-            src_shards[b] if src_shards[b] is not None
-            else slice_shard(src[b], b)
-            for b in range(nb)
-        ]
+        with jax.named_scope("deft_route"):
+            src_sh = [
+                src_shards[b] if src_shards[b] is not None
+                else slice_shard(src[b], b)
+                for b in range(nb)
+            ]
         scale = 1.0 / (n_dp * phase.update_k)
-        pbuf_sh, opt, _ = apply_bucket_updates(
-            opt_spec, segments, pbuf_sh, src_sh, opt,
-            grad_scale=scale, zero_grads=False, impl=update_impl,
-            shard_id=shard_id,
-            norm_psum=lambda t: jax.lax.psum(t, shard_axis),
-            master_dtype=master_dtype,
-        )
+        with jax.named_scope("deft_update"):
+            pbuf_sh, opt, _ = apply_bucket_updates(
+                opt_spec, segments, pbuf_sh, src_sh, opt,
+                grad_scale=scale, zero_grads=False, impl=update_impl,
+                shard_id=shard_id,
+                norm_psum=lambda t: jax.lax.psum(t, shard_axis),
+                master_dtype=master_dtype,
+            )
         pbuf_sh = list(pbuf_sh)
-        if consumed_cur and gen is not None:
-            new_cur = gen
-        else:
-            new_cur = [jnp.zeros_like(c) for c in cur_synced]
+        with jax.named_scope("deft_route"):
+            if consumed_cur and gen is not None:
+                new_cur = gen
+            else:
+                new_cur = [jnp.zeros_like(c) for c in cur_synced]
     elif phase.rotate:
         new_cur = gen
     else:
@@ -1339,9 +1391,9 @@ class DeftRuntime:
         # observability (DESIGN.md §11): control-plane events (swaps,
         # repacks, compile failures) always record into the tracer — the
         # legacy ``swap_log`` dicts are reconstructed from those events —
-        # but per-step phase/collective spans are only emitted when a
-        # tracer was explicitly attached, keeping the untraced hot path
-        # free of span bookkeeping.
+        # but per-step phase/place/launch spans enter the ring only when
+        # a tracer was explicitly attached.  Their profiler annotations
+        # are always opened: an inactive one costs one check.
         self.tracer = tracer if tracer is not None else Tracer(capacity=8192)
         self.trace_steps = tracer is not None
         self.last_phase = 0                # cycle phase of the last dispatch
@@ -2044,15 +2096,12 @@ class DeftRuntime:
                 "transition targets a different parameter tree than this "
                 "runtime's layout"
             )
-        with self._partial_donation_ok():
-            tr0 = self.tracer.now()
-            out = self._repack_jitted(transition)(state)
-            self.tracer.add(
-                "repack", "repack-state", tr0, self.tracer.now(),
-                moved_elems=transition.moved_elems,
-                n_buckets=transition.dst.n_buckets,
-            )
-            return out
+        with self._partial_donation_ok(), self.tracer.span(
+            "repack", "deft.repack",
+            moved_elems=transition.moved_elems,
+            n_buckets=transition.dst.n_buckets,
+        ):
+            return self._repack_jitted(transition)(state)
 
     def _swap_state_struct(self, state_abs, layout: BucketLayout):
         """Abstract post-repack train state under ``layout`` — what the
@@ -2363,30 +2412,102 @@ class DeftRuntime:
         installed first and ``i`` becomes step 0 of the new cycle; a
         layout-changing swap additionally re-packs the donated state
         through the staged transition before dispatching (the one-time
-        repack cost is recorded in ``swap_log``)."""
-        if self._pending is not None and (i - self._cycle_base) % self.period == 0:
-            pending, self._pending = self._pending, None
-            repack_s = None
-            if pending.layout is not None:
-                t0 = time.perf_counter()
-                tr0 = self.tracer.now()
+        repack cost is recorded in ``swap_log``).
+
+        The whole call is the host span ``deft.phase``; inside it
+        ``deft.place`` puts the batch on the executable's sharding and
+        ``deft.launch`` calls the executable (DESIGN.md §11).  They reach
+        an active profiler session's host plane always, and the tracer's
+        ring only when a tracer was attached."""
+        tracing = self.trace_steps
+        span = self.tracer.span
+        with span("phase", "deft.phase", record=tracing, step=i) as attrs:
+            if self._pending is not None \
+                    and (i - self._cycle_base) % self.period == 0:
+                state = self._install_pending(i, state)
+            off = (i - self._cycle_base) % self.period
+            self.last_phase = off
+            entry = self._unique_entries[self.phase_of_step[off]]
+            # an entry's first dispatch carries residual lazy work (jit
+            # trace+compile on the fallback branch, executable warm-up
+            # even when AOT-compiled) — tag it so telemetry can skip it
+            first = entry.stats.dispatches == 0
+            self.last_dispatch_first = first
+            clock = self.tracer.now if tracing else time.perf_counter
+            t0 = clock()
+            if entry.compiled is not None:
+                with span("place", "deft.place", record=tracing, step=i,
+                          phase=off):
+                    batch = jax.device_put(batch, entry.batch_sharding)
+                with span("launch", "deft.launch", record=tracing, step=i,
+                          phase=off):
+                    out = entry.compiled(state, batch)
+            else:  # compile() skipped — trace under the mesh on first hit
+                with span("launch", "deft.launch", record=tracing, step=i,
+                          phase=off), jax.set_mesh(self.mesh):
+                    out = entry.jitted(state, batch)
+            entry.stats.dispatches += 1
+            entry.stats.dispatch_s += clock() - t0
+            if tracing:
+                attrs.update(self._record_dispatch(i, off, first))
+        return out
+
+    def _record_dispatch(self, i: int, off: int, first: bool
+                         ) -> Dict[str, Any]:
+        """Record the update and gather-skip marks of traced step ``i``
+        at cycle phase ``off`` and return its ``phase`` span's
+        attributes: its update, its scheduled syncs per link and the
+        wire bytes they ship under the installed precision (what
+        ``obs.wire_bytes_report`` audits against the plan)."""
+        spec = self._unique_entries[self.phase_of_step[off]].spec
+        coll = self._coll_of_step[off]
+        wb_p, wb_s = self._wire_bytes_split_of_step[off]
+        if spec.do_update:
+            self.tracer.instant(
+                "update-apply", f"update-k{spec.update_k}",
+                step=i, phase=off, k=spec.update_k,
+                source=spec.update_source,
+            )
+        if self._reuse_of_step[off]:
+            self.tracer.instant("gather-skip", "gather-skip", step=i,
+                                phase=off)
+        return {
+            "phase": off, "first": first, "update": spec.do_update,
+            "primary": coll["primary"], "secondary": coll["secondary"],
+            "wire_bytes": self._wire_bytes_of_step[off],
+            "wire_bytes_primary": wb_p, "wire_bytes_secondary": wb_s,
+            "precision": (
+                self.layout.precision.describe()
+                if self.layout.precision is not None else "f32"
+            ),
+        }
+
+    def _install_pending(self, i: int, state: TrainState) -> TrainState:
+        """Install the armed schedule at cycle boundary ``i`` (host span
+        ``deft.swap-install``); a layout-changing swap first re-packs the
+        donated state through the staged transition (``deft.repack``)."""
+        pending, self._pending = self._pending, None
+        repack_s = None
+        if pending.layout is not None:
+            t0 = time.perf_counter()
+            with self.tracer.span(
+                "repack", "deft.repack", step=i,
+                moved_elems=pending.transition.moved_elems,
+                n_buckets=pending.layout.n_buckets,
+            ):
                 state = pending.repack(state)
                 jax.block_until_ready(jax.tree_util.tree_leaves(state))
-                repack_s = time.perf_counter() - t0
-                self.tracer.add(
-                    "repack", "swap-repack", tr0, self.tracer.now(),
-                    step=i, moved_elems=pending.transition.moved_elems,
-                    n_buckets=pending.layout.n_buckets,
-                )
-                self.layout = pending.layout
-                self._segments = pending.segments
-                self.layout_swaps += 1
+            repack_s = time.perf_counter() - t0
+            self.layout = pending.layout
+            self._segments = pending.segments
+            self.layout_swaps += 1
+        with self.tracer.span("swap-install", "deft.swap-install",
+                              step=i) as attrs:
             self._install(pending.schedule)
             self._cycle_base = i
             self.hot_swaps += 1
-            self.tracer.instant(
-                "swap-install", "swap-install",
-                step=i, period=pending.schedule.period,
+            attrs.update(
+                period=pending.schedule.period,
                 updates_per_period=pending.schedule.updates_per_period,
                 n_buckets=self.layout.n_buckets,
                 shards=self.layout.shards,
@@ -2396,57 +2517,7 @@ class DeftRuntime:
                     if self.layout.precision is not None else "f32"
                 ),
             )
-        off = (i - self._cycle_base) % self.period
-        self.last_phase = off
-        entry = self._unique_entries[self.phase_of_step[off]]
-        # an entry's first dispatch carries residual lazy work (jit
-        # trace+compile on the fallback branch, executable warm-up even
-        # when AOT-compiled) — tag it so telemetry can skip it (§11)
-        first = entry.stats.dispatches == 0
-        self.last_dispatch_first = first
-        tracing = self.trace_steps
-        clock = self.tracer.now if tracing else time.perf_counter
-        t0 = clock()
-        if entry.compiled is not None:
-            batch = jax.device_put(batch, entry.batch_sharding)
-            out = entry.compiled(state, batch)
-        else:  # compile() skipped — trace under the mesh on first hit
-            with jax.set_mesh(self.mesh):
-                out = entry.jitted(state, batch)
-        t1 = clock()
-        entry.stats.dispatches += 1
-        entry.stats.dispatch_s += t1 - t0
-        if tracing:
-            spec = entry.spec
-            self.tracer.add(
-                "phase", f"phase{off}", t0, t1, step=i, phase=off,
-                first=first, update=spec.do_update,
-            )
-            coll = self._coll_of_step[off]
-            wire = (
-                self.layout.precision.describe()
-                if self.layout.precision is not None else "f32"
-            )
-            wb_p, wb_s = self._wire_bytes_split_of_step[off]
-            self.tracer.add(
-                "collective-group", f"collectives@{off}", t0, t1,
-                step=i, phase=off,
-                primary=coll["primary"], secondary=coll["secondary"],
-                wire_bytes=self._wire_bytes_of_step[off],
-                wire_bytes_primary=wb_p, wire_bytes_secondary=wb_s,
-                precision=wire,
-            )
-            if spec.do_update:
-                self.tracer.instant(
-                    "update-apply", f"update-k{spec.update_k}",
-                    t=t1, step=i, phase=off, k=spec.update_k,
-                    source=spec.update_source,
-                )
-            if self._reuse_of_step[off]:
-                self.tracer.instant(
-                    "gather-skip", "gather-skip", t=t0, step=i, phase=off,
-                )
-        return out
+        return state
 
     # ---- reporting ------------------------------------------------------
     def phase_kernels(self) -> List[Dict[str, Any]]:
@@ -2460,6 +2531,15 @@ class DeftRuntime:
                  e.compiled.as_text())))}
             for e in self._unique_entries if e.compiled is not None
         ]
+
+    def phase_collective_scopes(self) -> List[Dict[str, FrozenSet[str]]]:
+        """Per AOT-compiled unique phase: the sync, gather and metrics
+        scopes of each instruction that is or calls a collective, read
+        from the compiled HLO (``obs.hlo_scopes``), so a collective XLA
+        rewrote or combined still names its buckets, links and
+        generations."""
+        return [collective_scopes([e.compiled.as_text()])
+                for e in self._unique_entries if e.compiled is not None]
 
     def collectives_per_phase(self) -> List[Dict[str, int]]:
         """Static per-schedule-phase collective counts (fused path)."""
@@ -2503,9 +2583,6 @@ class DeftRuntime:
             "compile_s_total": total_compile,
             "steps_dispatched": n,
             "dispatch_s_total": total_dispatch,
-            # dispatch-wall throughput: what the benchmarks report without
-            # re-deriving it from their own timers
-            "steps_per_s": n / total_dispatch if total_dispatch > 0 else 0.0,
             "replans": self.replans,
             "hot_swaps": self.hot_swaps,
             "layout_swaps": self.layout_swaps,

@@ -582,7 +582,7 @@ def test_adaptive_loop_hot_swap_bit_matches_reference(single_mesh):
     assert swap_step % schedule.period == 0
     assert runtime.period == new_schedule.period
     assert st["steps_dispatched"] == n_steps
-    assert st["steps_per_s"] > 0
+    assert st["dispatch_s_total"] > 0
 
     # staging the same schedule again is a pure cache hit
     re_info = runtime.prepare_swap(

@@ -333,8 +333,12 @@ def test_attribute_trace_excludes_first_dispatch_spans():
         # first cycle is compile-polluted: 50x the planned duration
         dur = planned[p] * (50.0 if step < period else 1.0)
         t0 = clk()
+        # the runtime's enqueue span is no measure of the phase: only the
+        # training loop's blocked step span is read
+        tr.add("phase", "deft.phase", t0, t0 + 1e-6, step=step, phase=p,
+               first=(step < period))
         clk.advance(dur)
-        tr.add("phase", f"phase{p}", t0, clk(), step=step, phase=p,
+        tr.add("step", f"step{step}", t0, clk(), step=step, phase=p,
                first=(step < period))
     measured = measured_phase_durations_from_trace(tr, period)
     for p in range(period):
@@ -521,6 +525,131 @@ def test_health_monitor_mirrors_detections_into_trace():
 
 
 # ---------------------------------------------------------------------------
+# The engine's named scopes in a compiled phase
+# ---------------------------------------------------------------------------
+def test_scope_names_parse():
+    from repro.obs.hlo_scopes import innermost, parse_sync
+
+    assert innermost("jit(f)/shard_map/deft_model/transpose(jvp())/dot"
+                     ) == "deft_model"
+    # XLA joins the names of merged instructions with ';'
+    assert innermost("jit(f)/shard_map/broadcast_in_dim;deft_route/"
+                     "broadcast_in_dim") == "deft_route"
+    assert innermost("jit(f)/shard_map/deft_update/deft_sync.b2.primary."
+                     "new/psum") == "deft_sync.b2.primary.new"
+    assert innermost("jit(f)/out") is None and innermost("") is None
+    assert parse_sync("deft_sync.b3.secondary.new") == (
+        "sync", 3, "secondary", "new")
+    assert parse_sync("deft_gather.b0.primary") == (
+        "gather", 0, "primary", None)
+    assert parse_sync("deft_route") is None
+
+
+_REWRITTEN = """HloModule m
+
+%region_rs (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0), metadata={op_name="deft_sync.b0.secondary.cur/reduce_scatter"}
+  %b = f32[] parameter(1), metadata={op_name="deft_sync.b0.secondary.cur/reduce_scatter"}
+  ROOT %add.1 = f32[] add(%a, %b), metadata={op_name="deft_sync.b0.secondary.cur/add"}
+}
+
+%region_sum (c: f32[], d: f32[]) -> f32[] {
+  %c = f32[] parameter(0)
+  %d = f32[] parameter(1)
+  ROOT %add.2 = f32[] add(%c, %d), metadata={op_name="deft_sync.b1.primary.new/add"}
+}
+
+%region_plain (e: f32[], f: f32[]) -> f32[] {
+  %e = f32[] parameter(0)
+  %f = f32[] parameter(1)
+  ROOT %add.3 = f32[] add(%e, %f)
+}
+
+%async_body (p: f32[8]) -> f32[32] {
+  %p = f32[8] parameter(0)
+  ROOT %all-gather.1 = f32[32] all-gather(%p), dimensions={0}, metadata={op_name="jit(f)/shard_map/deft_sync.b2.secondary.new/all_gather"}
+}
+
+ENTRY %main (x: f32[32], y: f32[32], u: f32[32], w: f32[3], z: f32[8], i: s32[]) -> f32[32] {
+  %x = f32[32] parameter(0)
+  %y = f32[32] parameter(1)
+  %u = f32[32] parameter(2)
+  %w = f32[3] parameter(3)
+  %z = f32[8] parameter(4)
+  %i = s32[] parameter(5)
+  %all-reduce.1 = f32[32] all-reduce(%x), to_apply=%region_rs
+  %dynamic-slice.1 = f32[8] dynamic-slice(%all-reduce.1, %i), dynamic_slice_sizes={8}
+  %all-gather.2 = f32[32] all-gather(%dynamic-slice.1), dimensions={0}, metadata={op_name="jit(f)/shard_map/deft_sync.b0.secondary.cur/all_gather"}
+  %start = (f32[8], f32[32]) fusion(%z), kind=kCustom, calls=%async_body
+  %all-reduce.2 = (f32[32], f32[32], f32[3]) all-reduce(%y, %u, %w), to_apply=%region_sum, metadata={op_name="jit(f)/shard_map/deft_sync.b1.primary.new/psum"}
+  %gte.0 = f32[32] get-tuple-element(%all-reduce.2), index=0, metadata={op_name="jit(f)/shard_map/deft_sync.b1.primary.new/psum"}
+  %gte.1 = f32[32] get-tuple-element(%all-reduce.2), index=1
+  %dynamic-slice.2 = f32[8] dynamic-slice(%gte.1, %i), dynamic_slice_sizes={8}
+  %all-gather.3 = f32[32] all-gather(%dynamic-slice.2), dimensions={0}, metadata={op_name="jit(f)/shard_map/deft_sync.b3.secondary.new/all_gather"}
+  %gte.2 = f32[3] get-tuple-element(%all-reduce.2), index=2, metadata={op_name="jit(f)/shard_map/deft_metrics/psum"}
+  %all-reduce.3 = f32[32] all-reduce(%x), to_apply=%region_plain
+  ROOT %multiply.1 = f32[32] multiply(%all-reduce.3, %gte.0), metadata={op_name="jit(f)/shard_map/deft_update/mul"}
+}
+"""
+
+
+def test_collective_scopes_follow_xla_rewrites():
+    """A collective XLA made without metadata is named by its reduction
+    computation; an async wrapper by the collective it calls; a combined
+    all-reduce by each tuple element's own scope or the sync its element
+    reaches; a collective whose result reaches no sync stays unnamed."""
+    from repro.obs import collective_scopes
+
+    got = collective_scopes([_REWRITTEN])
+    b0 = frozenset({"deft_sync.b0.secondary.cur"})
+    assert got["all-reduce.1"] == b0 and got["all-gather.2"] == b0
+    b2 = frozenset({"deft_sync.b2.secondary.new"})
+    assert got["start"] == b2 and got["all-gather.1"] == b2
+    assert got["all-reduce.2"] == {"deft_sync.b1.primary.new",
+                                   "deft_sync.b3.secondary.new",
+                                   "deft_metrics"}
+    assert got["all-gather.3"] == {"deft_sync.b3.secondary.new"}
+    assert got["all-reduce.3"] == frozenset()
+    assert set(got) == {"all-reduce.1", "all-gather.2", "start",
+                        "all-gather.1", "all-reduce.2", "all-gather.3",
+                        "all-reduce.3"}
+
+
+def test_collective_scopes_on_a_compiled_v5e_phase():
+    """The four-chip cell's phase as the TPU compiler writes it (compiled
+    for a described v5e:2x2; the entry computation and the computations
+    its collectives call, layouts and backend configs cut): its
+    reduce-scatters run as all-reduces without metadata, and four
+    buckets' syncs and the metrics psum share one combined all-reduce.
+    Every collective is named, on the links the plan gave its buckets."""
+    import pathlib
+
+    from repro.obs import collective_scopes
+    from repro.obs.hlo_scopes import parse_sync
+
+    text = (pathlib.Path(__file__).parent / "data"
+            / "v5e_2x2_deft_phase.hlo.txt").read_text()
+    got = collective_scopes([text])
+    sec = lambda b, gen="new": f"deft_sync.b{b}.secondary.{gen}"
+    assert got["all-reduce.13"] == {sec(0, "cur")}      # 1.56 GB, no metadata
+    assert got["all-reduce.9"] == {sec(4)}
+    assert got["psum.21"] == {"deft_sync.b1.primary.new"}
+    assert got["psum.22"] == {"deft_sync.b5.primary.new"}
+    assert got["async-collective-start.1"] == {sec(3)}
+    assert got["all-reduce.14"] == {sec(2), sec(7), sec(8), "deft_metrics"}
+    assert all(got.values())
+    # the plan: buckets 1 and 5 on the primary link, the rest secondary
+    buckets = set()
+    for found in got.values():
+        for scope in found - {"deft_metrics"}:
+            _, b, link, gen = parse_sync(scope)
+            assert link == ("primary" if b in (1, 5) else "secondary")
+            assert gen == ("cur" if b == 0 else "new")
+            buckets.add(b)
+    assert buckets == set(range(9))
+
+
+# ---------------------------------------------------------------------------
 # Runtime integration: spans, swap_log shim, overhead bound
 # ---------------------------------------------------------------------------
 B, S = 4, 32
@@ -565,7 +694,13 @@ def test_runtime_trace_and_swap_log_shim(single_mesh):
     n_steps = 2 * schedule.period + new_schedule.period
     with jax.set_mesh(single_mesh):
         for step in range(n_steps):
+            t_s = tracer.now()
             state, m = runtime.step(step, state, make_batch(cfg, 0, step, B, S))
+            # the training loop's blocked step span, as launch/train.py records it
+            jax.block_until_ready(m["loss"])
+            tracer.add("step", f"step{step}", t_s, tracer.now(), step=step,
+                       phase=runtime.last_phase,
+                       first=runtime.last_dispatch_first)
             if step == 0:
                 assert runtime.last_dispatch_first        # cold tag
             if step == 2 * schedule.period - 1:
@@ -579,14 +714,23 @@ def test_runtime_trace_and_swap_log_shim(single_mesh):
                                      background=False)
         jax.block_until_ready(m["loss"])
 
-    # per-step spans: one phase + one collective-group per dispatch,
-    # first-dispatch tagging on exactly the unique executables
+    # per-step spans: one phase ⊃ launch per dispatch carrying the
+    # planned wire bytes (place only for AOT-compiled phases: the jitted
+    # fallback takes the batch as it comes), first-dispatch tagging on
+    # exactly the unique executables
     phases = tracer.spans("phase")
     assert len(phases) == n_steps
+    assert all(sp.name == "deft.phase" for sp in phases)
     assert all(sp.phase is not None and sp.duration > 0 for sp in phases)
+    assert all("wire_bytes" in sp.args for sp in phases)
     firsts = [sp for sp in phases if sp.args.get("first")]
     assert firsts and firsts[0].step == 0
-    assert len(tracer.spans("collective-group")) == n_steps
+    phase_of = {sp.step: sp for sp in phases}
+    launches = tracer.spans("launch")
+    assert [sp.step for sp in launches] == [sp.step for sp in phases]
+    for sp in launches + tracer.spans("place"):
+        ph = phase_of[sp.step]
+        assert ph.t0 <= sp.t0 <= sp.t1 <= ph.t1
 
     # control-plane spans + the swap_log compat shim
     assert len(tracer.spans("swap-compile")) == 1
@@ -621,6 +765,67 @@ def test_untraced_runtime_records_control_plane_only(single_mesh):
             state, m = runtime.step(step, state,
                                     make_batch(cfg, 0, step, B, S))
     assert runtime.tracer.spans("phase") == []
+
+
+def _host_spans(path, prefix="deft."):
+    """(name, start ns, end ns) of the host-plane events under
+    ``prefix`` in the profiler session written to ``path``."""
+    import glob
+
+    xplane = glob.glob(str(path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(xplane) == 1, xplane
+    pd = jax.profiler.ProfileData.from_file(xplane[0])
+    out = []
+    for plane in pd.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events if e.name.startswith(prefix)]
+    return sorted(out, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["tracer", "no-tracer"])
+def test_runtime_spans_reach_profiler_host_plane(single_mesh, tmp_path,
+                                                  traced):
+    """Under a profiler session every step shows on the host plane as
+    ``deft.phase`` holding ``deft.place`` and ``deft.launch``, in the
+    ring's order when a tracer is attached; without one the ring keeps
+    no per-step span, and the annotations still reach the profiler."""
+    cfg = _tiny_cfg()
+    key = jax.random.PRNGKey(0)
+    params = init_params(key, cfg)
+    _, schedule, _, layout = _tiny_schedule(cfg, params)
+    tracer = Tracer(capacity=256) if traced else None
+    runtime = DeftRuntime(cfg, adamw(1e-3), schedule, layout, single_mesh,
+                          tracer=tracer)
+    n_steps = schedule.period + 1
+    with jax.set_mesh(single_mesh):
+        state = runtime.init_state(key)
+        runtime.compile(state, make_batch(cfg, 0, 0, B, S))
+        with jax.profiler.trace(str(tmp_path)):
+            for step in range(n_steps):
+                state, m = runtime.step(step, state,
+                                        make_batch(cfg, 0, step, B, S))
+                jax.block_until_ready(m["loss"])
+    host = _host_spans(tmp_path)
+    phases = [e for e in host if e[0] == "deft.phase"]
+    assert len(phases) == n_steps
+    for name in ("deft.place", "deft.launch"):
+        inner = [e for e in host if e[0] == name]
+        assert len(inner) == n_steps
+        assert all(p[1] <= e[1] <= e[2] <= p[2]
+                   for p, e in zip(phases, inner))
+    if traced:
+        ring = tracer.spans("phase")
+        assert [sp.step for sp in ring] == list(range(n_steps))
+        # the ring's spans and the profiler's events time the same calls
+        ring_s = [sp.duration for sp in ring]
+        host_s = [(e - b) * 1e-9 for _, b, e in phases]
+        assert all(h <= r + 1e-3 and r <= h + 1e-3
+                   for h, r in zip(host_s, ring_s))
+    else:
+        assert runtime.tracer.spans(("phase", "place", "launch")) == []
 
 
 @pytest.mark.slow

@@ -432,8 +432,8 @@ def test_precision_hot_swap_at_cycle_boundary(single_mesh):
 
 
 def test_runtime_wire_bytes_match_plan(single_mesh):
-    """The bytes the executed collectives ship (collective-group span
-    attrs) must equal what the knapsack priced — the §13 acceptance
+    """The bytes the executed collectives ship (phase span attrs) must
+    equal what the knapsack priced — the §13 acceptance
     loop: policy -> pricing -> execution -> measured attribution."""
     from repro.obs import Tracer, wire_bytes_report
     from repro.train import DeftRuntime

@@ -8,7 +8,9 @@ pytest-xdist worker collects the same tests and only the worker given
 this file loads the TPU compiler."""
 from __future__ import annotations
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,3 +145,79 @@ def test_stochastic_round_bf16_compiles(one_chip):
     seed = _spec(one_chip, (), jnp.int32)
     compiled = jax.jit(stochastic_round_bf16_pallas).lower(buf, seed).compile()
     assert "stochastic_round_bf16" in compiled.as_text()
+
+
+def test_flat_phase_names_buckets_links_and_update_on_four_chips(topo):
+    """A four-chip phase of the replicated flat engine at test widths,
+    with a plan that uses both links: every collective the compiled
+    program holds, rewritten or combined by XLA or not, names the sync
+    scope of its buckets on their planned link and generation (or the
+    metrics psum); every synced bucket is named; every bucket_update
+    kernel runs under ``deft_update``."""
+    from jax.sharding import AxisType
+
+    from repro.configs import get_config
+    from repro.core.scheduler import DeftSchedule, PhaseSpec
+    from repro.data.pipeline import batch_spec
+    from repro.models.model import init_params
+    from repro.obs.hlo_scopes import METRICS_SCOPE, innermost, parse_sync
+    from repro.train import assign_buckets, build_bucket_layout
+    from repro.train.runtime import DeftRuntime, RuntimeConfig
+
+    cfg = dataclasses.replace(
+        get_config("qwen3-4b"), n_layers=2, d_model=128, n_heads=4,
+        n_kv_heads=2, head_dim=32, d_ff=256, vocab_size=1024)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    bucket_of, nb = assign_buckets(params, cfg, partition_elems=60_000)
+    layout = build_bucket_layout(params, bucket_of, nb)
+    assert nb >= 4
+    # the four-chip cell's form: the oldest bucket syncs its older
+    # generation, the rest this step's; links alternate
+    phase = PhaseSpec(
+        route_new=("current",) + ("sync",) * (nb - 1),
+        sync_cur=(True,) + (False,) * (nb - 1),
+        secondary=tuple(b % 3 != 1 for b in range(nb)),
+        rotate=True, do_update=True, update_k=1, update_source="cur")
+    schedule = DeftSchedule(plans=(), phases=(phase,), period=1,
+                            updates_per_period=1, batch_size_sequence=(1,))
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(4, 1),
+                             ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+    rt = DeftRuntime(cfg, adamw(3e-4), schedule, layout, mesh,
+                     config=RuntimeConfig(compute_dtype=jnp.bfloat16,
+                                          update_impl="pallas"))
+    rep = NamedSharding(mesh, PartitionSpec())
+    split = NamedSharding(mesh, PartitionSpec("data"))
+
+    def sds(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    bufs = tuple(sds((n,), jnp.float32, rep) for n in layout.buf_sizes)
+    accs = tuple(sds((4, n), jnp.float32, split) for n in layout.buf_sizes)
+    state = {"pbuf": bufs, "opt": {"step": sds((), jnp.int32, rep),
+                                   "m": bufs, "v": bufs},
+             "cur": accs, "fut": accs}
+    batch = jax.tree.map(lambda s: sds(s.shape, s.dtype, split),
+                         batch_spec(cfg, 8, 128))
+    rt.compile(state, batch)
+
+    (scopes,) = rt.phase_collective_scopes()
+    assert scopes
+    named = set()
+    for name, found in scopes.items():
+        assert found, f"{name} names no scope"
+        for scope in found - {METRICS_SCOPE}:
+            kind, b, link, gen = parse_sync(scope)
+            assert link == ("secondary" if phase.secondary[b]
+                            else "primary"), (name, scope)
+            assert gen == ("cur" if phase.sync_cur[b] else "new"), scope
+            named.add(b)
+    assert named == set(range(nb))
+    assert any(METRICS_SCOPE in found for found in scopes.values())
+
+    text = rt.phase_executable(0).as_text()
+    kernels = re.findall(
+        r"^\s*%(bucket_update[\w.-]*) = .*?custom_call_target="
+        r"\"tpu_custom_call\".*?op_name=\"([^\"]*)\"", text, re.M)
+    assert kernels and all(innermost(op) == "deft_update"
+                           for _, op in kernels), kernels
