@@ -40,7 +40,7 @@ from repro.models.common import (
     init_norm,
     softcap,
 )
-from repro.sharding import constrain
+from repro.sharding import constrain, shard_count
 from repro.util.flags import scan_unroll_enabled
 
 
@@ -308,6 +308,14 @@ def head_logits(params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     return logits
 
 
+def head_slice_width(rows: int, seq: int, chunk: int) -> int:
+    """Rows a vocabulary slice of the LM head's backward takes from each
+    shard of ``rows``: the largest multiple of 128 whose ``[B, seq, Vc]``
+    f32 block is no larger than the forward's ``[B, chunk, rows]`` block,
+    at least 128 and at most ``rows``."""
+    return min(rows, max(128, chunk * rows // seq // 128 * 128))
+
+
 def chunked_ce(
     params,
     cfg: ArchConfig,
@@ -317,45 +325,137 @@ def chunked_ce(
     chunk: int,
     unroll: bool = False,
 ) -> jax.Array:
-    """Sequence-chunked LM head + cross entropy.
+    """LM head + cross entropy that never holds the [B, S, V] logits.
 
     The [B, S, V] logits tensor dominates train-step memory at production
     shapes (gemma2-2b train_4k: ~4 TB of f32 logits+softmax temporaries
-    globally); computing head+CE per sequence chunk under jax.checkpoint
-    caps the live logits buffer at [B, chunk, V] in both passes."""
-    b, s, d = x.shape
-    chunk = min(chunk, s)
-    pad = (-s) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        targets = jnp.pad(targets, ((0, 0), (0, pad)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad))) if mask is not None else (
-            jnp.pad(jnp.ones((b, s), jnp.float32), ((0, 0), (0, pad)))
-        )
-    elif mask is None:
-        mask = jnp.ones((b, s), jnp.float32)
-    n = x.shape[1] // chunk
-    xs = x.reshape(b, n, chunk, d).swapaxes(0, 1)
-    ys = targets.reshape(b, n, chunk).swapaxes(0, 1)
-    ms = mask.reshape(b, n, chunk).swapaxes(0, 1)
-    xs = constrain(xs, (None, "batch", None, "embed"))
+    globally).  The forward runs the head and CE per sequence chunk, so
+    its live logits block is [B, chunk, V], and keeps only the per-token
+    log-partition ``logz`` ([B, S] f32) for the backward.
 
-    @jax.checkpoint
-    def body(carry, sl):
-        xc, yc, mc = sl
-        xc = constrain(xc, ("batch", None, "embed"))
-        logits = head_logits(params, cfg, xc).astype(jnp.float32)
-        logits = constrain(logits, ("batch", None, "vocab"))
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, yc[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * mc
-        return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(mc)), None
+    The backward goes by vocabulary slices instead, each over all B*S
+    tokens at once: recompute the slice's logits from x, form
+    dlogits = (softmax - onehot) * mask * g / count (times softcap's
+    derivative), write that slice's rows of the head's [V, d] gradient
+    with one product over K = B*S tokens, and add its part of dx into an
+    f32 accumulator.  Each row of the weight gradient is written once;
+    a backward by sequence chunks would instead add a whole [V, d]
+    partial product into an accumulator once per chunk, reading and
+    writing the full table gradient S / chunk times.  The slice width
+    Vc is :func:`head_slice_width`: the largest multiple of 128 whose
+    [B, S, Vc] f32 block is no larger than the forward's [B, chunk, V]
+    block (qwen3-4b, S = 4095, chunk 256: Vc = 9472); a vocabulary that
+    Vc does not divide ends in one narrower slice.  Where the rules split
+    the vocabulary over a mesh axis, V and Vc are counted per shard and a
+    slice takes the same rows of every shard, so no table row moves
+    between chips.  Both passes run under the named scope ``lm_head``."""
+    if cfg.tie_embeddings:
+        table = params["embed"]["table"]
+    else:
+        table = params["head"]["w"].T
+    if mask is None:
+        mask = jnp.ones(targets.shape, jnp.float32)
+    return _head_ce(table, x, targets, mask.astype(jnp.float32),
+                    float(cfg.final_logit_softcap or 0.0), chunk,
+                    bool(unroll or scan_unroll_enabled()))
 
-    (total, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xs, ys, ms), unroll=n if (unroll or scan_unroll_enabled()) else 1,
-    )
-    return total / jnp.maximum(count, 1.0)
+
+def _f32_logits(raw, cap):
+    """Head products (compute dtype) -> f32 logits (+ softcap)."""
+    logits = raw.astype(jnp.float32)
+    if cap:
+        logits = cap * jnp.tanh(logits / cap)
+    return logits
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _head_ce(table, x, targets, mask, cap, chunk, unroll):
+    return _head_ce_fwd(table, x, targets, mask, cap, chunk, unroll)[0]
+
+
+def _head_ce_fwd(table, x, targets, mask, cap, chunk, unroll):
+    with jax.named_scope("lm_head"):
+        b, s, d = x.shape
+        chunk = min(chunk, s)
+        pad = (-s) % chunk
+        pads = ((0, 0), (0, pad))
+        xp = jnp.pad(x, (*pads, (0, 0)))
+        n = xp.shape[1] // chunk
+        xs = xp.reshape(b, n, chunk, d).swapaxes(0, 1)
+        ys = jnp.pad(targets, pads).reshape(b, n, chunk).swapaxes(0, 1)
+        ms = jnp.pad(mask, pads).reshape(b, n, chunk).swapaxes(0, 1)
+        xs = constrain(xs, (None, "batch", None, "embed"))
+
+        def body(carry, sl):
+            xc, yc, mc = sl
+            xc = constrain(xc, ("batch", None, "embed"))
+            raw = constrain(xc @ table.T, ("batch", None, "vocab"))
+            logits = _f32_logits(raw, cap)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, yc[..., None], axis=-1)[..., 0]
+            return carry + jnp.sum((logz - gold) * mc), logz
+
+        total, logz = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                                   (xs, ys, ms), unroll=n if unroll else 1)
+        logz = logz.swapaxes(0, 1).reshape(b, n * chunk)[:, :s]
+        count = jnp.sum(mask)
+        loss = total / jnp.maximum(count, 1.0)
+        # shard-major view for the backward: row r of shard k is
+        # vocabulary id k*span + r (one shard without a vocab axis)
+        v = table.shape[0]
+        m = shard_count("vocab", v)
+        shards = table.reshape(m, v // m, d)
+    return loss, (shards, x, targets, mask, logz, count)
+
+
+def _head_ce_bwd(cap, chunk, unroll, res, g):
+    shards, x, targets, mask, logz, count = res
+    with jax.named_scope("lm_head"):
+        b, s, _ = x.shape
+        # each slice takes the same rows of every vocabulary shard, so
+        # nothing crosses shards inside the loop
+        m, span, d = shards.shape
+        vc = head_slice_width(span, s, min(chunk, s))
+        n, rem = divmod(span, vc)
+        shards = constrain(shards, ("vocab", None, "embed"))
+        first = (jnp.arange(m) * span)[:, None]
+        x = constrain(x, ("batch", None, "embed"))
+        scale = (mask * (g / jnp.maximum(count, 1.0)))[..., None, None]
+
+        def slice_grads(dx, start, w):          # w: [m, width, d]
+            raw = jnp.einsum("bsd,mvd->bsmv", x, w)
+            raw = constrain(raw, ("batch", None, "vocab", None))
+            logits = _f32_logits(raw, cap)
+            ids = first + start + jnp.arange(w.shape[1])
+            onehot = targets[..., None, None] == ids
+            dl = (jnp.exp(logits - logz[..., None, None]) - onehot) * scale
+            if cap:
+                dl = dl * (1.0 - jnp.square(logits / cap))
+            dl = constrain(dl.astype(raw.dtype),
+                           ("batch", None, "vocab", None))
+            dw = jnp.einsum("bsmv,bsd->mvd", dl, x,
+                            preferred_element_type=jnp.float32)
+            # dx stays split by shard until the loop ends
+            dx = dx + jnp.einsum("bsmv,mvd->mbsd", dl, w,
+                                 preferred_element_type=jnp.float32)
+            return dx, dw.astype(shards.dtype)
+
+        def body(dx, i):
+            w = jax.lax.dynamic_slice_in_dim(shards, i * vc, vc, axis=1)
+            return slice_grads(dx, i * vc, w)
+
+        dx = constrain(jnp.zeros((m, b, s, d), jnp.float32),
+                       ("vocab", "batch", None, "embed"))
+        dx, dws = jax.lax.scan(body, dx, jnp.arange(n),
+                               unroll=n if unroll else 1)
+        dw = dws.swapaxes(0, 1).reshape(m, n * vc, d)
+        if rem:
+            dx, tail = slice_grads(dx, n * vc, shards[:, n * vc:])
+            dw = jnp.concatenate([dw, tail], axis=1)
+    return dw.reshape(m * span, d), dx.sum(0).astype(x.dtype), None, None
+
+
+_head_ce.defvjp(_head_ce_fwd, _head_ce_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -378,16 +478,16 @@ def loss_fn(
     memory = batch.get("memory")
     if cfg.is_encoder_decoder:
         memory = encode(params, cfg, memory, unroll=unroll)
+    mask = batch.get("mask")
+    mask = mask[:, 1:] if mask is not None else None
     if loss_chunk:
         x, _, aux = forward(
             params, cfg, batch["tokens"], memory=memory,
             capacity_factor=capacity_factor, remat=remat, head=False,
             unroll=unroll,
         )
-        mask = batch.get("mask")
         loss = chunked_ce(
-            params, cfg, x[:, :-1], batch["labels"][:, 1:],
-            mask[:, 1:] if mask is not None else None, loss_chunk,
+            params, cfg, x[:, :-1], batch["labels"][:, 1:], mask, loss_chunk,
             unroll=unroll,
         )
     else:
@@ -396,7 +496,7 @@ def loss_fn(
             capacity_factor=capacity_factor, remat=remat, unroll=unroll,
         )
         loss = cross_entropy_loss(
-            logits[:, :-1], batch["labels"][:, 1:], batch.get("mask")
+            logits[:, :-1], batch["labels"][:, 1:], mask
         )
     return loss + aux, {"ce": loss, "aux": aux}
 

@@ -93,6 +93,15 @@ def constrain(x: jax.Array, names: Sequence[Optional[str]]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, spec_for(names, x.shape))
 
 
+def shard_count(name: str, size: int) -> int:
+    """Into how many pieces :func:`constrain` splits a dimension of
+    ``size`` named ``name`` (1 where no rules are active)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if _current_rules() is None or mesh is None or not mesh.axis_names:
+        return 1
+    return _axis_prod(dict(mesh.shape), spec_for((name,), (size,))[0])
+
+
 # ---------------------------------------------------------------------------
 # Canonical rule tables
 # ---------------------------------------------------------------------------

@@ -203,3 +203,60 @@ print("PALLAS_SHARDED_OK")
 @pytest.mark.multidevice
 def test_pallas_attention_runs_per_shard_under_plain_jit(tmp_path):
     assert "PALLAS_SHARDED_OK" in _run(tmp_path, _PALLAS_SHARDED_SCRIPT, 300)
+
+
+# The chunked LM head's backward under a plain jit with the vocabulary
+# split over 'model' (the DDP step's form): each vocabulary slice takes
+# the same rows of every shard, so GSPMD moves no table rows between
+# chips (no all-gather) and loss and gradients match the whole-sequence
+# loss.  700 rows: 350 a shard, two 128-row slices and a 94-row tail.
+_HEAD_SHARDED_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, sys.argv[1])
+import dataclasses
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.configs import get_config, reduce_for_smoke
+from repro.data.pipeline import make_batch
+from repro.models.model import init_params, loss_fn
+from repro.sharding import logical_rules, rules_pjit
+from repro.sharding.specs import param_rules, spec_tree
+
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+for name in ("gemma2-2b", "deepseek-7b"):
+    cfg = dataclasses.replace(reduce_for_smoke(get_config(name)),
+                              vocab_size=700)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    batch = make_batch(cfg, 0, 0, 4, 32)
+    grads = {}
+    with jax.set_mesh(mesh):
+        specs = spec_tree(params, param_rules(cfg.name, False, "tp"), mesh)
+        placed = jax.tree.map(
+            lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
+            params, specs)
+        b = jax.tree.map(
+            lambda a: jax.device_put(a, NamedSharding(mesh, P("data"))), batch)
+        for chunk in (0, 8):
+            def f(p, b, chunk=chunk):
+                with logical_rules(rules_pjit(False, False)):
+                    return loss_fn(p, cfg, b, loss_chunk=chunk)[0]
+            step = jax.jit(jax.value_and_grad(f))
+            if chunk:
+                hlo = step.lower(placed, b).compile().as_text()
+                assert "all-gather" not in hlo, name
+            grads[chunk] = step(placed, b)
+    (l0, g0), (l8, g8) = grads[0], grads[8]
+    np.testing.assert_allclose(l8, l0, rtol=1e-6)
+    for a, c in zip(jax.tree.leaves(g8), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(a, c, atol=1e-5)
+print("HEAD_SHARDED_OK")
+"""
+
+
+@pytest.mark.multidevice
+def test_chunked_head_backward_stays_on_its_vocab_shard(tmp_path):
+    assert "HEAD_SHARDED_OK" in _run(tmp_path, _HEAD_SHARDED_SCRIPT, 300)

@@ -6,13 +6,14 @@ from its ImageNet runs — here it is exact (to f32 reduction order).
 Runs on a 1x1 mesh — the full shard_map/psum graph is built; a true
 multi-device run of the same check lives in test_multidevice.py.
 """
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from _jaxpr_census import count_primitives
+from _jaxpr_census import count_primitives, iter_eqns
 from repro.configs import get_config, reduce_for_smoke
 from repro.core.bucket import BucketTimes
 from repro.core.deft import solve_schedule
@@ -276,20 +277,79 @@ def test_low_cr_full_update_frequency_and_progress(single_mesh):
     assert losses[-1] < losses[0]
 
 
-def test_loss_chunk_matches_unchunked(single_mesh):
-    """Chunked LM-head CE == plain CE (same loss, same gradients)."""
-    cfg = reduce_for_smoke(get_config("gemma2-2b"))   # softcaps + tied embed
-    key = jax.random.PRNGKey(2)
+def _loss_case(name, vocab=None, masked=False):
+    cfg = reduce_for_smoke(get_config(name))
+    if vocab:
+        cfg = dataclasses.replace(cfg, vocab_size=vocab)
     from repro.models.model import init_params
-    params = init_params(key, cfg)
+    params = init_params(jax.random.PRNGKey(2), cfg)
     batch = make_batch(cfg, 0, 0, B, S)
+    if masked:
+        keep = jax.random.bernoulli(jax.random.PRNGKey(3), 0.7, (B, S))
+        batch["mask"] = keep.astype(jnp.float32)
+    return cfg, params, batch
+
+
+@pytest.mark.parametrize("name,chunk,vocab,masked", [
+    ("gemma2-2b", 8, None, False),     # tied embed + final softcap
+    ("qwen3-4b", 8, None, False),      # tied, no softcap
+    ("deepseek-7b", 8, None, False),   # untied head [d, V]
+    ("qwen3-4b", 8, None, True),       # a mask with zeros
+    ("qwen3-4b", 12, None, False),     # a chunk that does not divide S - 1
+    ("gemma2-2b", 8, 700, True),       # V = 5 x 128 + a 60-wide last slice
+], ids=["tied-softcap", "tied", "untied", "masked", "ragged-chunk",
+        "ragged-vocab"])
+def test_loss_chunk_matches_unchunked(single_mesh, name, chunk, vocab,
+                                      masked):
+    """Chunked LM-head CE == plain CE (same loss, same gradients)."""
+    cfg, params, batch = _loss_case(name, vocab, masked)
     l1, _ = loss_fn(params, cfg, batch, loss_chunk=0)
-    l2, _ = loss_fn(params, cfg, batch, loss_chunk=8)
+    l2, _ = loss_fn(params, cfg, batch, loss_chunk=chunk)
     assert float(jnp.abs(l1 - l2)) < 1e-5
     g1 = jax.grad(lambda p: loss_fn(p, cfg, batch, loss_chunk=0)[0])(params)
-    g2 = jax.grad(lambda p: loss_fn(p, cfg, batch, loss_chunk=8)[0])(params)
+    g2 = jax.grad(
+        lambda p: loss_fn(p, cfg, batch, loss_chunk=chunk)[0])(params)
+    assert jax.tree.structure(g1) == jax.tree.structure(g2)
     diff = max(
         float(jnp.max(jnp.abs(a - b)))
         for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2))
     )
     assert diff < 1e-4
+
+
+@pytest.mark.parametrize("rows,seq,chunk,width", [
+    (151_936, 4095, 256, 9472),   # qwen3-4b, one 4,096-token sequence
+    (512, 31, 8, 128),            # smoke: never under 128 rows
+    (512, 31, 31, 512),           # one chunk: one slice of every row
+])
+def test_head_slice_width_fits_the_forward_block(rows, seq, chunk, width):
+    from repro.models.model import head_slice_width
+    assert head_slice_width(rows, seq, chunk) == width
+    assert width % 128 == 0 or width == rows
+    assert width * seq <= max(chunk * rows, 128 * seq)
+
+
+@pytest.mark.parametrize("name", ["qwen3-4b", "deepseek-7b"])
+def test_chunked_loss_grad_carries_no_head_sized_loop_state(name):
+    """The chunked loss's backward writes the head's [V, d] gradient once:
+    no loop of ``jax.grad`` carries an array of the head's full shape
+    (a backward by sequence chunks would add each chunk's product into
+    such a carry)."""
+    cfg, params, batch = _loss_case(name)
+    head = {(cfg.vocab_size, cfg.d_model), (cfg.d_model, cfg.vocab_size)}
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: loss_fn(p, cfg, batch, loss_chunk=8)[0]))(params).jaxpr
+    loops = 0
+    for eqn in iter_eqns(jaxpr):
+        if eqn.primitive.name == "scan":
+            n = eqn.params["num_consts"]
+            carry = eqn.params["jaxpr"].in_avals[
+                n:n + eqn.params["num_carry"]]
+        elif eqn.primitive.name == "while":
+            carry = eqn.params["body_jaxpr"].in_avals[
+                eqn.params["body_nconsts"]:]
+        else:
+            continue
+        loops += 1
+        assert not {tuple(a.shape) for a in carry} & head, eqn.primitive
+    assert loops
