@@ -1,5 +1,6 @@
 """Model FLOPs of the traced steps over the chips' bf16 peak for the
-traced window (``bench/flops.py``: 6 x matmul parameters plus
+traced window (``bench/flops.py``, counted by the configuration's
+architecture module: for the dense block 6 x matmul parameters plus
 attention over the visible keys, no recompute)."""
 from bench import flops
 

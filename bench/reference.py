@@ -1,24 +1,12 @@
 """Plain reference of the cells' training steps.
 
-It imports nothing of the program.  It reads the published sizes from
-the configuration file and the parameter tree's names and shapes (the
-interface: ``embed/table``, ``final_norm``, and per layer ``pre_norm``,
-``mixer/{wq,wk,wv,wo[,q_norm,k_norm]}``, ``ffn_norm``,
-``ffn/{gate,up,down}`` or ``ffn/{up,down}``, stacked on a leading layer
-axis), and starts from the benchmark's own seeded weights.
-
-One sequence at a time, in float32 with matrix products at
-``Precision.HIGHEST``: token embedding; per layer a pre-norm, grouped-
-query causal attention (per-head RMS q/k norms where the model has
-them, rotary embedding with the two halves of each head rotated
-together, softmax scale 1/sqrt(head_dim), keys inside the sliding
-window where one is set), a residual, a pre-norm and the MLP (gated
-SiLU or tanh-GELU), a residual; a final norm, the tied LM head and the
-mean next-token cross entropy.  Attention runs in blocks of queries and
-the loss in blocks of positions, each recomputed in the backward pass,
-so that 4,096 tokens fit.  Departures the configuration states (norm
-epsilon, no biases in linear layers) are read from its file; an RMSNorm
-weight is ``1 + scale``, as the program writes it.
+It imports nothing of the program.  The loss of one sequence is the
+configuration's architecture module's (``bench/archs``,
+:func:`row_loss`), which reads the published sizes from the
+configuration file and the parameter tree's names and shapes; it starts
+from the benchmark's own seeded weights.  Here is what every
+architecture shares: the matrix products, the fp8 control and the
+training that follows the plan.
 
 Training follows the plan's update list (:func:`train`): at step i the
 gradient of the global batch is taken at the current weights, and where
@@ -38,6 +26,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # largest finite value of 4 exponent and 3 mantissa bits with an
@@ -70,140 +60,35 @@ def _q8_bwd(_, g):
 _q8.defvjp(_q8_fwd, _q8_bwd)
 
 
-def _ein(precision: str):
-    """einsum(spec, activation, operand); under fp8 the activation is
+def einsum_for(precision: str):
+    """einsum(subscripts, activation, operand); under fp8 the activation is
     rounded here, and so is the operand unless ``rounded`` says the
     caller rounded it once already (a weight)."""
     if precision == "fp8":
-        def ein(spec, a, b, rounded=False):
-            return jnp.einsum(spec, _q8(a), b if rounded else _q8(b),
+        def ein(subs, a, b, rounded=False):
+            return jnp.einsum(subs, _q8(a), b if rounded else _q8(b),
                               precision=HIGHEST)
         return ein
-    return lambda spec, a, b, rounded=False: jnp.einsum(
-        spec, a, b, precision=HIGHEST)
+    return lambda subs, a, b, rounded=False: jnp.einsum(
+        subs, a, b, precision=HIGHEST)
 
 
-_MATRICES = ("wq", "wk", "wv", "wo", "gate", "up", "down", "table")
-
-
-def _round_weights(params):
-    """Each matrix rounded to fp8 once per step, not at every use."""
+def round_weights(params, names: Sequence[str]):
+    """Each leaf whose last key is in ``names`` rounded to fp8 once per
+    step, not at every use."""
     def one(path, x):
         last = getattr(path[-1], "key", None)
-        return _q8(x) if last in _MATRICES else x
+        return _q8(x) if last in names else x
     return jax.tree_util.tree_map_with_path(one, params)
-
-
-def _eps(c: Dict[str, Any]) -> float:
-    return c["rms_norm_eps"] if c["norm"] == "rmsnorm" else c["norm_epsilon"]
-
-
-def _norm(p, x, c):
-    eps = _eps(c)
-    if c["norm"] == "rmsnorm":
-        y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-        return y * (1.0 + p["scale"])
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _rms_head(x, scale, eps):
-    y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-    return y * (1.0 + scale)
-
-
-def _rope(x, theta):
-    """x [S, H, D]; the first and second halves of D rotate as pairs."""
-    s, _, d = x.shape
-    freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(p, h, c, ein, block: int):
-    s = h.shape[0]
-    nh, nkv = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c.get("head_dim") or c["hidden_size"] // nh
-    g = nh // nkv
-    window = c.get("sliding_window") or 0
-    q = ein("sd,de->se", h, p["wq"], True).reshape(s, nh, hd)
-    k = ein("sd,de->se", h, p["wk"], True).reshape(s, nkv, hd)
-    v = ein("sd,de->se", h, p["wv"], True).reshape(s, nkv, hd)
-    if c.get("qk_norm"):
-        q = _rms_head(q, p["q_norm"], _eps(c))
-        k = _rms_head(k, p["k_norm"], _eps(c))
-    q = _rope(q, c["rope_theta"]).reshape(s, nkv, g, hd)
-    k = _rope(k, c["rope_theta"])
-    kpos = jnp.arange(s)
-
-    @jax.checkpoint
-    def one_block(qb, start):
-        qpos = start + jnp.arange(qb.shape[0])
-        sc = ein("qkgd,tkd->kgqt", qb, k) / jnp.sqrt(jnp.float32(hd))
-        ok = kpos[None, :] <= qpos[:, None]
-        if window:
-            ok &= kpos[None, :] > qpos[:, None] - window
-        sc = jnp.where(ok, sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        return ein("kgqt,tkd->qkgd", pr, v)
-
-    outs = [one_block(q[i:i + block], i) for i in range(0, s, block)]
-    o = jnp.concatenate(outs, 0).reshape(s, nh * hd)
-    return ein("se,ed->sd", o, p["wo"], True)
-
-
-def _mlp(p, h, c, ein):
-    if c["hidden_act"] == "silu":
-        a = jax.nn.silu(ein("sd,df->sf", h, p["gate"], True))
-        a = a * ein("sd,df->sf", h, p["up"], True)
-    else:
-        a = jax.nn.gelu(ein("sd,df->sf", h, p["up"], True),
-                        approximate=True)
-    return ein("sf,fd->sd", a, p["down"], True)
-
-
-def _layers(params) -> List[Dict[str, Any]]:
-    if params.get("prefix") or params.get("tail"):
-        raise ValueError("the reference knows only a stacked layer pattern")
-    out = []
-    for stacked in params["stack"]:
-        n = jax.tree.leaves(stacked)[0].shape[0]
-        out += [jax.tree.map(lambda x, i=i: x[i], stacked) for i in range(n)]
-    return out
 
 
 def row_loss(params, tokens, c: Dict[str, Any], precision: str = "f32",
              block: int = 512, positions: Optional[int] = None):
-    """Mean next-token cross entropy of one sequence ``tokens`` [S];
-    ``positions`` keeps only the first that many targets."""
-    ein = _ein(precision)
-    x = params["embed"]["table"][tokens]
-    if precision == "fp8":
-        params = _round_weights(params)
-    table = params["embed"]["table"]
-    for p in _layers(params):
-        layer = jax.checkpoint(
-            lambda x, p: x + _attention(p["mixer"], _norm(p["pre_norm"], x, c),
-                                        c, ein, block))
-        x = layer(x, p)
-        x = x + _mlp(p["ffn"], _norm(p["ffn_norm"], x, c), c, ein)
-    x = _norm(params["final_norm"], x, c)
-    h, y = x[:-1], tokens[1:]
-    n = h.shape[0] if positions is None else positions
-
-    @jax.checkpoint
-    def chunk(hc, yc):
-        logits = ein("sd,vd->sv", hc, table, True)
-        lz = jax.nn.logsumexp(logits, -1)
-        gold = jnp.take_along_axis(logits, yc[:, None], -1)[:, 0]
-        return jnp.sum(lz - gold)
-
-    tot = sum(chunk(h[i:min(i + block, n)], y[i:min(i + block, n)])
-              for i in range(0, n, block))
-    return tot / n
+    """The loss of one sequence ``tokens`` [S] by the configuration's
+    architecture module (``bench/archs``); ``positions`` keeps only the
+    first that many targets."""
+    return spec.arch(c).row_loss(params, tokens, c, precision, block,
+                                 positions)
 
 
 def leaf_names(tree) -> List[str]:

@@ -97,22 +97,9 @@ class CompileCounter:
 
 # ---- the program, built as launch/train.py builds it -----------------------
 def program_config(c: Dict[str, Any]):
-    """The program's ArchConfig at the file's sizes."""
-    from repro.configs import get_config
-
-    act = {"silu": "silu", "gelu_pytorch_tanh": "gelu_mlp"}[c["hidden_act"]]
-    base = get_config(c["arch"])
-    kw = dict(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim") or 0, d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], rope_theta=float(c["rope_theta"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]), norm=c["norm"],
-        use_qk_norm=bool(c.get("qk_norm")), ffn_activation=act,
-        sliding_window=c.get("sliding_window") or 0,
-    )
-    return dataclasses.replace(base, **kw)
+    """The program's ArchConfig at the file's sizes, by the
+    configuration's architecture module (``bench/archs``)."""
+    return spec.arch(c).program_config(c)
 
 
 def plan_updates(schedule, n: int):
@@ -198,8 +185,9 @@ def pool_tokens(cell: Cell, seed: int):
     t = cell.traffic
     rows = cell.chips * t["batch_per_chip"]
     st = t["stream"]
+    vocab = program_config(cell.cfg).vocab_size
     return jax.jit(lambda k: weights.token_pool(
-        k, t["pool"] * rows, t["seq"], cell.cfg["vocab_size"],
+        k, t["pool"] * rows, t["seq"], vocab,
         st["zipf_exponent"], st["follow_p"]))(weights.base_key(seed))
 
 

@@ -3,12 +3,15 @@ limit and peak files it names, and the checks of their form.
 
 Everything a cell needs is found by name: ``configs/<config>.json``,
 ``traffic/<traffic>.json``, ``limits/<workload>.json`` and
-``metrics/<metric>.py`` under this directory.  Adding a cell, a
-configuration, a traffic mix or a per-layer metric is adding files and
-entries; no file here changes.
+``metrics/<metric>.py`` under this directory, and the architecture
+module that the configuration file names (``arch_module``, a module
+under ``archs/``; the contract is in ``archs/__init__.py``).  Adding a
+cell, a configuration, an architecture, a traffic mix or a per-layer
+metric is adding files and entries; no file here changes.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import pathlib
 import re
@@ -21,6 +24,11 @@ MANIFEST = ROOT / "BENCHMARK.json"
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+MODULE_RE = re.compile(r"^[A-Za-z_][A-Za-z0-9_]*(\.[A-Za-z_][A-Za-z0-9_]*)*$")
+# the functions every architecture module has (archs/__init__.py)
+ARCH_CONTRACT = ("program_config", "leaf_kind", "row_loss", "matrices",
+                 "total_params", "model_flops_per_token", "attn_fwd_cost",
+                 "tiny")
 
 
 class SpecError(ValueError):
@@ -53,6 +61,22 @@ def limits(workload: str) -> Dict[str, Any]:
 
 def peaks() -> Dict[str, Any]:
     return _load(BENCH_DIR / "peaks.json")
+
+
+def arch(c: Dict[str, Any]):
+    """The architecture module that configuration ``c`` names with
+    ``arch_module`` (a dotted name under the ``bench`` package)."""
+    name = c.get("arch_module")
+    if not isinstance(name, str) or not MODULE_RE.match(name):
+        raise SpecError(f"arch_module {name!r} does not name a module")
+    try:
+        mod = importlib.import_module(f"bench.{name}")
+    except ModuleNotFoundError as e:
+        raise SpecError(f"arch_module {name!r}: {e}") from None
+    missing = [f for f in ARCH_CONTRACT if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SpecError(f"arch_module {name!r} lacks {', '.join(missing)}")
+    return mod
 
 
 def workload(man: Dict[str, Any], name: str) -> Dict[str, Any]:
@@ -93,8 +117,14 @@ def validate(man: Dict[str, Any]) -> List[str]:
         line("source", c["source"])
         for k in c["reduced"]:
             name("reduced key", k)
-        if not (ROOT / c["file"]).is_file():
+        path = ROOT / c["file"]
+        if not path.is_file():
             bad.append(f"config file {c['file']} missing")
+            continue
+        try:
+            arch(_load(path))
+        except SpecError as e:
+            bad.append(f"config {c['name']}: {e}")
     wls = {w["name"]: w for w in man["workloads"]}
     pairs = set()
     for w in man["workloads"]:

@@ -1,5 +1,6 @@
-"""bench/flops.py against hand counts of the benchmark's configuration
-and of the StarCoder2 test configuration (LayerNorm, non-gated MLP)."""
+"""bench/flops.py and the dense module's counts (bench/archs/dense.py)
+against hand counts of the benchmark's configuration and of the
+StarCoder2 test configuration (LayerNorm, non-gated MLP)."""
 import json
 
 import pytest
@@ -7,6 +8,7 @@ import pytest
 from conftest import DATA
 
 from bench import flops, spec
+from bench.archs import dense
 
 
 def test_qwen3_4b_l1_counts():
@@ -14,7 +16,7 @@ def test_qwen3_4b_l1_counts():
     d, ff, v, hd = 2560, 9728, 151_936, 128
     attn = d * 32 * hd + 2 * d * 8 * hd + 32 * hd * d      # q, k, v, o
     mlp = 3 * d * ff                                        # gate, up, down
-    assert flops.matmul_params(c) == attn + mlp + v * d == 489_881_600
+    assert dense.matmul_params(c) == attn + mlp + v * d == 489_881_600
     # + 2 RMSNorm weights a layer, the q/k norms, the final norm
     assert flops.total_params(c) == 489_881_600 + 2 * d + 2 * hd + d
     per_tok = 6 * 489_881_600 + 3 * 4 * 32 * hd * (4096 + 1) / 2
@@ -27,7 +29,7 @@ def test_starcoder2_7b_l1_counts():
     d, ff, v, hd = 4608, 18432, 49_152, 128
     attn = d * 36 * hd + 2 * d * 4 * hd + 36 * hd * d
     mlp = 2 * d * ff                                        # up, down
-    assert flops.matmul_params(c) == attn + mlp + v * d == 443_547_648
+    assert dense.matmul_params(c) == attn + mlp + v * d == 443_547_648
     # LayerNorm scale and bias: two a layer and the final one
     assert flops.total_params(c) == 443_547_648 + 3 * 2 * d
     # a window of 4,096 over 4,096 tokens hides no key
@@ -38,9 +40,9 @@ def test_starcoder2_7b_l1_counts():
 
 def test_mean_context_inside_a_window():
     # 8 positions, window 4: positions 0..3 see 1..4 keys, 4..7 see 4
-    assert flops.mean_context(8, 4) == (1 + 2 + 3 + 4 + 4 * 4) / 8
-    assert flops.mean_context(8, 0) == 4.5
-    assert flops.mean_context(8, 8) == 4.5
+    assert dense.mean_context(8, 4) == (1 + 2 + 3 + 4 + 4 * 4) / 8
+    assert dense.mean_context(8, 0) == 4.5
+    assert dense.mean_context(8, 8) == 4.5
 
 
 def test_attention_forward_and_update_bytes():

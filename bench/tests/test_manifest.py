@@ -72,3 +72,31 @@ def test_a_cell_that_does_not_report_the_moved_metric_is_caught(man):
     tok = next(m for m in bad["end_to_end"] if m["name"] == "tokens_per_s")
     tok["workloads"] = [bad["workloads"][0]["name"]]
     assert any("does not report" in p for p in spec.validate(bad))
+
+
+@pytest.mark.parametrize("arch_module,reason", [
+    (None, "does not name a module"),
+    ("../archs/dense", "does not name a module"),
+    ("archs.no_such_arch", "No module named"),
+    ("compare", "lacks program_config, leaf_kind"),
+    ("archs.dense", "lacks tiny"),
+], ids=["missing", "not_a_name", "no_such_module", "not_an_arch",
+        "lacks_a_function"])
+def test_validate_refuses_a_config_without_its_arch_module(
+        man, tmp_path, monkeypatch, arch_module, reason):
+    from bench.archs import dense
+
+    c = spec.config(man["configs"][0]["name"])
+    c.pop("arch_module")
+    if arch_module is not None:
+        c["arch_module"] = arch_module
+    if reason == "lacks tiny":
+        monkeypatch.delattr(dense, "tiny")
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(c))
+    bad = copy.deepcopy(man)
+    bad["configs"][0]["file"] = str(path)
+    problems = spec.validate(bad)
+    assert len(problems) == 1 and "\n" not in problems[0], problems
+    assert problems[0].startswith(f"config {man['configs'][0]['name']}: ")
+    assert reason in problems[0]

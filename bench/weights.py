@@ -4,13 +4,15 @@ The benchmark makes its own initial weights, so the reference starts
 from values the program never made.  Each leaf of the program's
 parameter tree (the shapes come from ``DeftRuntime.checkpoint_struct``)
 is drawn from its own key, rounded to bfloat16 (the compute type) and
-held in float32 (the master type):
+held in float32 (the master type), by the kind that the configuration's
+architecture module (``bench/archs``, ``leaf_kind``) gives its name:
 
-* ``embed/table`` normal(0, 0.02);
-* a matrix normal(0, 1/sqrt(fan_in)), fan_in its second-to-last axis;
-* an RMSNorm weight, which the program writes as ``1 + scale``,
-  ``scale`` ~ normal(0, 0.02); a LayerNorm ``scale`` 1 + normal(0, 0.02)
-  and its ``bias`` normal(0, 0.02).
+* ``embed`` normal(0, 0.02);
+* ``matrix`` normal(0, 1/sqrt(fan_in)), fan_in its second-to-last axis;
+* ``rms_scale``, an RMSNorm weight, which the program writes as
+  ``1 + scale``: ``scale`` ~ normal(0, 0.02); ``ln_scale``, a LayerNorm
+  ``scale``: 1 + normal(0, 0.02); ``ln_bias``, its ``bias``:
+  normal(0, 0.02).
 
 The batch stream has the distribution of the program's synthetic
 stream (``data/pipeline.make_batch``): a Markov chain that follows a
@@ -25,6 +27,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from bench import spec
+
 
 def base_key(seed: int) -> jax.Array:
     """A key from any non-negative seed (PRNGKey keeps 32 bits)."""
@@ -38,42 +42,29 @@ def _path_names(path) -> tuple:
     return tuple(out)
 
 
-def leaf_kind(names: tuple, ndim: int, norm: str) -> str:
-    """'embed', 'matrix', 'rms_scale', 'ln_scale' or 'ln_bias'."""
-    last = names[-1]
-    if last == "table":
-        return "embed"
-    if last in ("q_norm", "k_norm"):
-        return "rms_scale"
-    if last == "bias":
-        return "ln_bias"
-    if last == "scale":
-        return "rms_scale" if norm == "rmsnorm" else "ln_scale"
-    if ndim >= 2:       # stacked leaves keep a leading layer axis
-        return "matrix"
-    raise ValueError(f"unknown parameter {'/'.join(names)}")
-
-
 def init_params(struct, cfg: Dict[str, Any], key: jax.Array):
     """The params tree of ``struct`` (a ShapeDtypeStruct tree), drawn
     from ``key`` (:func:`base_key` of the seed); call under ``jax.jit``
     with the key as an argument, so one compiled program serves every
     seed."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(struct)
+    leaf_kind = spec.arch(cfg).leaf_kind
     leaves = []
     for i, (path, s) in enumerate(flat):
         names = _path_names(path)
         k = jax.random.fold_in(key, i)
         z = jax.random.normal(k, s.shape, jnp.float32)
-        kind = leaf_kind(names, len(s.shape), cfg["norm"])
+        kind = leaf_kind(names, len(s.shape), cfg)
         if kind == "embed":
             x = 0.02 * z
         elif kind == "matrix":
             x = z / jnp.sqrt(jnp.float32(s.shape[-2]))
         elif kind == "ln_scale":
             x = 1.0 + 0.02 * z
-        else:
+        elif kind in ("rms_scale", "ln_bias"):
             x = 0.02 * z
+        else:
+            raise ValueError(f"{'/'.join(names)}: no draw for kind {kind!r}")
         # reduce_precision, not a round trip through bf16: XLA may drop
         # a convert pair as excess precision, and did, in some programs
         # and not in others
