@@ -20,6 +20,7 @@ from repro.configs.shapes import (
 from repro.configs import (  # noqa: E402  (import order: registry modules)
     deepseek_7b,
     deepseek_v2_236b,
+    deepseek_v2_lite,
     gemma2_2b,
     llama4_maverick_400b_a17b,
     llama_3_2_vision_90b,
@@ -37,6 +38,7 @@ _REGISTRY: Dict[str, ArchConfig] = {
         deepseek_7b,
         starcoder2_7b,
         deepseek_v2_236b,
+        deepseek_v2_lite,
         rwkv6_1_6b,
         seamless_m4t_large_v2,
         llama4_maverick_400b_a17b,
@@ -50,7 +52,7 @@ _REGISTRY[gemma2_2b.LONG_CONTEXT_CONFIG.name] = gemma2_2b.LONG_CONTEXT_CONFIG
 
 ARCH_NAMES = tuple(
     n for n in _REGISTRY if not n.endswith("-longctx")
-)  # the 10 assigned ids
+)  # the assigned ids
 
 
 def get_config(name: str) -> ArchConfig:
@@ -88,12 +90,13 @@ def reduce_for_smoke(cfg: ArchConfig, n_layers: int = 2) -> ArchConfig:
             n_shared_experts=min(cfg.moe.n_shared_experts, 1),
             d_expert=128,
             first_k_dense=min(cfg.moe.first_k_dense, 1),
+            experts_held=min(cfg.moe.experts_held, 4),
         )
     mla = None
     if cfg.mla is not None:
         mla = MLAConfig(
             kv_lora_rank=64,
-            q_lora_rank=96,
+            q_lora_rank=None if cfg.mla.q_lora_rank is None else 96,
             qk_nope_head_dim=32,
             qk_rope_head_dim=16,
             v_head_dim=32,

@@ -50,6 +50,12 @@ class LayerSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """A routed-expert FFN.  ``n_experts`` is the router's width: every
+    token is scored over all of them.  A layer's parameters hold
+    ``experts_held`` of them (0: all), ids 0 .. experts_held - 1, and it
+    computes only the (token, choice) pairs that chose one it holds (one
+    chip's share of an expert-parallel layer)."""
+
     n_experts: int
     experts_per_token: int
     n_shared_experts: int = 0
@@ -58,6 +64,19 @@ class MoEConfig:
     # Layers at the start of the stack that stay dense even if the pattern
     # says 'moe' (DeepSeek-V2 keeps layer 0 dense).
     first_k_dense: int = 0
+    experts_held: int = 0
+    # renormalise the top-k weights to sum to 1 (DeepSeek-V2: no)
+    norm_topk_prob: bool = True
+    # auxiliary loss per sequence (DeepSeek-V2 ``seq_aux``) rather than
+    # over the whole batch
+    seq_aux: bool = False
+
+    def __post_init__(self):
+        assert 0 <= self.experts_held <= self.n_experts
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,10 +84,23 @@ class MLAConfig:
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)."""
 
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: one d -> H x qk projection
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    (``rope_scaling`` of its config)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +119,7 @@ class ArchConfig:
 
     # --- attention details -------------------------------------------------
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None
     use_qk_norm: bool = False
     sliding_window: int = 0     # window size for 'local_attn' layers
     attn_logit_softcap: float = 0.0
@@ -163,8 +196,11 @@ class ArchConfig:
         if spec.kind == "mla":
             m = self.mla
             qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
-            p = d * m.q_lora_rank                      # q down
-            p += m.q_lora_rank * self.n_heads * qk_head  # q up
+            if m.q_lora_rank is None:
+                p = d * self.n_heads * qk_head         # q
+            else:
+                p = d * m.q_lora_rank                  # q down
+                p += m.q_lora_rank * self.n_heads * qk_head  # q up
             p += d * (m.kv_lora_rank + m.qk_rope_head_dim)  # kv down (+rope k)
             p += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
             p += self.n_heads * m.v_head_dim * d       # o proj
@@ -193,7 +229,7 @@ class ArchConfig:
             me = self.moe
             de = me.d_expert or self.d_ff
             per_expert = 3 * d * de  # gated: up, gate, down
-            total = (me.n_experts + me.n_shared_experts) * per_expert
+            total = (me.held + me.n_shared_experts) * per_expert
             total += d * me.n_experts  # router
             return total
         mult = 3 if self.ffn_activation in ("silu", "gelu") else 2
@@ -237,8 +273,10 @@ class ArchConfig:
             + self.d_model  # final norm
         )
 
-    def active_params(self) -> int:
-        """Parameters touched per token (MoE: only routed-active experts)."""
+    def active_params(self) -> float:
+        """Parameters touched per token (MoE: only routed-active experts;
+        a layer that holds a share of the experts runs a token through
+        each held expert with probability top-k / n_experts)."""
         if self.moe is None:
             return self.total_params()
         me = self.moe
@@ -249,7 +287,8 @@ class ArchConfig:
             for i, s in enumerate(self.layer_specs())
             if s.ffn == "moe" and i >= me.first_k_dense
         )
-        inactive = n_moe_layers * (me.n_experts - me.experts_per_token) * per_expert
+        routed = me.experts_per_token * me.held / me.n_experts
+        inactive = n_moe_layers * (me.held - routed) * per_expert
         return self.total_params() - inactive
 
     # --- FLOPs per token (fwd). bwd ~ 2x fwd. -------------------------------

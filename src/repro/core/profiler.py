@@ -138,13 +138,16 @@ def _layer_flops_fwd(cfg: ArchConfig, seq_len: int, per_device_batch: int) -> Li
     out.append(enc_flops + 2.0 * tokens * cfg.d_model)  # embed scale etc.
     hd = cfg.resolved_head_dim
     for i, spec in enumerate(specs):
-        # matmul term: 2 * active params of this layer
+        # matmul term: 2 * active params of this layer; a token runs
+        # through k * held / E of the experts this layer holds, the shared
+        # experts and the router over all E
         if spec.ffn == "moe" and cfg.moe and i >= cfg.moe.first_k_dense:
             me = cfg.moe
             de = me.d_expert or cfg.d_ff
+            routed = me.experts_per_token * me.held / me.n_experts
             active = (
                 cfg._attn_params(spec)
-                + (me.experts_per_token + me.n_shared_experts) * 3 * cfg.d_model * de
+                + (routed + me.n_shared_experts) * 3 * cfg.d_model * de
                 + cfg.d_model * me.n_experts
             )
         else:

@@ -24,7 +24,9 @@ Two variants:
                   slice — FLOPs O(S * window), which is what makes 32k+
                   prefill with a 2-4k window tractable.
 
-GQA is handled by folding the q heads into (kv_head, group).
+GQA is handled by folding the q heads into (kv_head, group).  v may be
+narrower than q/k (latent attention); the softmax scale is ``sm_scale``,
+1/sqrt(D) of the q/k heads unless given.
 """
 from __future__ import annotations
 
@@ -43,6 +45,16 @@ def _fold_gqa(q: jax.Array, kvh: int) -> jax.Array:
     """[B, Sq, H, D] -> [B, Sq, KVH, G, D]."""
     b, sq, h, d = q.shape
     return q.reshape(b, sq, kvh, h // kvh, d)
+
+
+def _scaled_q(q5, d: int, sm_scale):
+    """q in f32 times the softmax scale (1/sqrt(d) unless given)."""
+    q5 = q5.astype(jnp.float32)
+    return q5 / jnp.sqrt(d) if sm_scale is None else q5 * sm_scale
+
+
+def _bwd_scale(d: int, sm_scale):
+    return 1.0 / jnp.sqrt(d) if sm_scale is None else sm_scale
 
 
 def _chunk_mask(qpos, kpos, *, causal: bool, window: int, sk: int):
@@ -67,14 +79,15 @@ def _scores(q5f, kf, softcap: float):
 # ---------------------------------------------------------------------------
 # Global (full / causal) attention
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_global(q, k, v, causal: bool, softcap: float, q_offset: int,
-                 chunk: int):
-    out, _ = _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk)
+                 chunk: int, sm_scale=None):
+    out, _ = _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk,
+                              sm_scale)
     return out
 
 
-def _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk):
+def _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk, sm_scale):
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
     dv = v.shape[-1]                   # MLA: d_qk (192) != d_v (128)
@@ -85,7 +98,7 @@ def _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk):
     nk = kp.shape[1] // chunk
     kc = kp.reshape(b, nk, chunk, kvh, d).transpose(1, 0, 2, 3, 4)
     vc = vp.reshape(b, nk, chunk, kvh, dv).transpose(1, 0, 2, 3, 4)
-    q5f = _fold_gqa(q, kvh).astype(jnp.float32) / jnp.sqrt(d)
+    q5f = _scaled_q(_fold_gqa(q, kvh), d, sm_scale)
     qpos = q_offset + jnp.arange(sq)
 
     def step(carry, xs):
@@ -117,12 +130,13 @@ def _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk):
     return out, lse
 
 
-def _global_fwd(q, k, v, causal, softcap, q_offset, chunk):
-    out, lse = _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk)
+def _global_fwd(q, k, v, causal, softcap, q_offset, chunk, sm_scale):
+    out, lse = _global_fwd_impl(q, k, v, causal, softcap, q_offset, chunk,
+                                sm_scale)
     return out, (q, k, v, out, lse)
 
 
-def _global_bwd(causal, softcap, q_offset, chunk, res, gout):
+def _global_bwd(causal, softcap, q_offset, chunk, sm_scale, res, gout):
     q, k, v, out, lse = res
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
@@ -136,7 +150,7 @@ def _global_bwd(causal, softcap, q_offset, chunk, res, gout):
     kc = kp.reshape(b, nk, chunk, kvh, d).transpose(1, 0, 2, 3, 4)
     vc = vp.reshape(b, nk, chunk, kvh, dv).transpose(1, 0, 2, 3, 4)
 
-    scale = 1.0 / jnp.sqrt(d)
+    scale = _bwd_scale(d, sm_scale)
     q5f = _fold_gqa(q, kvh).astype(jnp.float32) * scale
     g5 = _fold_gqa(gout, kvh).astype(jnp.float32)        # [B,Sq,KVH,G,D]
     o5 = _fold_gqa(out, kvh).astype(jnp.float32)
@@ -205,10 +219,11 @@ def _local_block(q5f, kblk, vblk, qpos, kpos, softcap, sk, window):
     return out5, p, t, mask
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_local(q, k, v, window: int, softcap: float, q_offset: int,
-                block_q: int):
-    out, _ = _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q)
+                block_q: int, sm_scale=None):
+    out, _ = _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q,
+                             sm_scale)
     return out
 
 
@@ -216,13 +231,13 @@ def _pad_kv(k, span, block_q):
     return jnp.pad(k, ((0, 0), (span, block_q), (0, 0), (0, 0)))
 
 
-def _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q):
+def _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q, sm_scale):
     b, sq, h, d, sk, block_q, pad_q, nq, span = _local_geometry(
         q, k, window, block_q
     )
     kvh = k.shape[2]
     qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    q5 = _fold_gqa(qp, kvh).astype(jnp.float32) / jnp.sqrt(d)
+    q5 = _scaled_q(_fold_gqa(qp, kvh), d, sm_scale)
     qb = q5.reshape(b, nq, block_q, kvh, h // kvh, d).transpose(1, 0, 2, 3, 4, 5)
     kp = _pad_kv(k, span, block_q)
     vp = _pad_kv(v, span, block_q)
@@ -246,12 +261,13 @@ def _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q):
     return out[:, :sq].astype(q.dtype), None
 
 
-def _local_fwd(q, k, v, window, softcap, q_offset, block_q):
-    out, _ = _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q)
+def _local_fwd(q, k, v, window, softcap, q_offset, block_q, sm_scale):
+    out, _ = _local_fwd_impl(q, k, v, window, softcap, q_offset, block_q,
+                             sm_scale)
     return out, (q, k, v)
 
 
-def _local_bwd(window, softcap, q_offset, block_q, res, gout):
+def _local_bwd(window, softcap, q_offset, block_q, sm_scale, res, gout):
     q, k, v = res
     b, sq, h, d, sk, block_q, pad_q, nq, span = _local_geometry(
         q, k, window, block_q
@@ -260,7 +276,7 @@ def _local_bwd(window, softcap, q_offset, block_q, res, gout):
     g = h // kvh
     qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
     gp = jnp.pad(gout, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    scale = 1.0 / jnp.sqrt(d)
+    scale = _bwd_scale(d, sm_scale)
     q5 = _fold_gqa(qp, kvh).astype(jnp.float32) * scale
     g5 = _fold_gqa(gp, kvh).astype(jnp.float32)
     qb = q5.reshape(b, nq, block_q, kvh, g, d).transpose(1, 0, 2, 3, 4, 5)

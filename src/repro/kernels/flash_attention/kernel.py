@@ -8,8 +8,10 @@ fully masked are skipped with ``pl.when`` — the skip is structural (the
 MXU work is never issued), which is what makes local attention
 sub-quadratic on the long_500k path.
 
-Layout: q [B, H, Sq, D], k/v [B, KV, Sk, D]; GQA is handled in the
-BlockSpec index_map (head h reads kv head h // (H // KV)).  Besides the
+Layout: q/k [B, H|KV, S, D], v [B, KV, Sk, Dv] (latent attention's v
+heads are narrower than its q/k heads); GQA is handled in the BlockSpec
+index_map (head h reads kv head h // (H // KV)).  The softmax scale is
+``sm_scale``, 1/sqrt(D) unless given.  Besides the
 output the kernel writes each row's log-sum-exp [B, H, Sq], the one
 residual the backward pass (ops.py) needs to recompute probabilities.
 """
@@ -17,7 +19,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -101,13 +103,14 @@ def _attn_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "window", "softcap", "block_q", "block_kv", "interpret"
+        "causal", "window", "softcap", "block_q", "block_kv", "interpret",
+        "sm_scale",
     ),
 )
 def flash_attention_pallas(
     q: jax.Array,  # [B, H, Sq, D]
     k: jax.Array,  # [B, KV, Sk, D]
-    v: jax.Array,
+    v: jax.Array,  # [B, KV, Sk, Dv]
     *,
     causal: bool = True,
     window: int = 0,
@@ -115,17 +118,20 @@ def flash_attention_pallas(
     block_q: int = 128,
     block_kv: int = 128,
     interpret: bool = False,
+    sm_scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns ``(out [B, H, Sq, D], lse [B, H, Sq] f32)``."""
+    """Returns ``(out [B, H, Sq, Dv], lse [B, H, Sq] f32)``."""
     b, h, sq, d = q.shape
     _, kvh, sk, _ = k.shape
+    dv = v.shape[-1]
     assert h % kvh == 0
     group = h // kvh
     block_q = min(block_q, sq)
     block_kv = min(block_kv, sk)
     assert sq % block_q == 0 and sk % block_kv == 0, (sq, block_q, sk, block_kv)
     nq, nk = sq // block_q, sk // block_kv
-    sm_scale = 1.0 / math.sqrt(d)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
 
     kernel = functools.partial(
         _attn_kernel,
@@ -147,13 +153,13 @@ def flash_attention_pallas(
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d),
+                (1, 1, block_kv, dv),
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
         ],
         out_specs=[
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+                (1, 1, block_q, dv), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
             ),
             # a unit sublane dim keeps the block a legal (1, 128k) tile
             pl.BlockSpec(
@@ -162,11 +168,11 @@ def flash_attention_pallas(
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],

@@ -14,7 +14,9 @@
                  the O(S^2) score tensor is cheap and autodiff through it
                  is the fastest option.
 
-All impls share the layout q [B, Sq, H, D], k/v [B, Sk, KV, D].
+All impls share the layout q [B, Sq, H, D], k [B, Sk, KV, D], v
+[B, Sk, KV, Dv] (Dv may differ from D: latent attention), and the
+softmax scale ``sm_scale`` (1/sqrt(D) unless given).
 """
 from __future__ import annotations
 
@@ -22,7 +24,6 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.flash import (
@@ -42,109 +43,10 @@ from repro.sharding import spec_for
 _REF_MAX_SEQ = 1024
 
 
-def _blocked_global(
-    q, k, v, *, causal: bool, softcap: float, q_offset: int, chunk: int
-) -> jax.Array:
-    """Online-softmax scan over kv chunks (no window)."""
-    b, sq, h, d = q.shape
-    _, sk, kvh, _ = k.shape
-    group = h // kvh
-    pad = (-sk) % chunk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    nk = k.shape[1] // chunk
-    kc = k.reshape(b, nk, chunk, kvh, d).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, nk, chunk, kvh, d).transpose(1, 0, 2, 3, 4)
-    qf = q.astype(jnp.float32) / jnp.sqrt(d)
-    qpos = q_offset + jnp.arange(sq)
-
-    def step(carry, xs):
-        acc, m, l = carry
-        ic, kblk, vblk = xs
-        kf = jnp.repeat(kblk.astype(jnp.float32), group, axis=2)
-        vf = jnp.repeat(vblk.astype(jnp.float32), group, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf)
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        kpos = ic * chunk + jnp.arange(chunk)
-        mask = kpos[None, :] < sk
-        if causal:
-            mask = mask & (kpos[None, :] <= qpos[:, None])
-        s = jnp.where(mask[None, None], s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bhqk,bkhd->bhqd", p, vf)
-        return (acc, m_new, l), None
-
-    acc0 = jnp.zeros((b, h, sq, d), jnp.float32)
-    m0 = jnp.full((b, h, sq), -1e30, jnp.float32)
-    l0 = jnp.zeros((b, h, sq), jnp.float32)
-    (acc, _, l), _ = jax.lax.scan(
-        step, (acc0, m0, l0), (jnp.arange(nk), kc, vc)
-    )
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
-
-
-def _blocked_local(
-    q, k, v, *, window: int, softcap: float, q_offset: int, block_q: int
-) -> jax.Array:
-    """Sliding-window attention: per q-block dynamic slice of the kv range
-    [q_start - window + 1, q_start + block_q) — FLOPs O(S * window), not
-    O(S^2)."""
-    b, sq, h, d = q.shape
-    _, sk, kvh, _ = k.shape
-    pad_q = (-sq) % block_q
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    nq = q.shape[1] // block_q
-    span = window + block_q  # kv positions any query in the block can see
-    # pad kv on both sides so every dynamic slice is in-bounds (the last q
-    # block's span can run one block past the sequence end)
-    pad_left = span
-    kp = jnp.pad(k, ((0, 0), (pad_left, block_q), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (pad_left, block_q), (0, 0), (0, 0)))
-    qb = q.reshape(b, nq, block_q, h, d).transpose(1, 0, 2, 3, 4)
-
-    def per_block(iq, qblk):
-        # absolute kv start of the visible span for this q block
-        q_start = q_offset + iq * block_q
-        kv_start = q_start - window + 1  # may be negative; padding absorbs
-        start = kv_start + pad_left
-        kblk = jax.lax.dynamic_slice(kp, (0, start, 0, 0), (b, span, kvh, d))
-        vblk = jax.lax.dynamic_slice(vp, (0, start, 0, 0), (b, span, kvh, d))
-        kpos = kv_start + jnp.arange(span)
-        qpos = q_start + jnp.arange(block_q)
-        valid = (kpos[None, :] >= 0) & (kpos[None, :] < sk)
-        valid &= kpos[None, :] <= qpos[:, None]
-        valid &= kpos[None, :] > qpos[:, None] - window
-        out = _masked_naive(qblk, kblk, vblk, valid, softcap)
-        return out
-
-    outs = jax.vmap(per_block)(jnp.arange(nq), qb)  # [nq, B, bq, H, D]
-    out = outs.transpose(1, 0, 2, 3, 4).reshape(b, nq * block_q, h, d)
-    return out[:, :sq]
-
-
-def _masked_naive(q, k, v, mask, softcap):
-    b, sq, h, d = q.shape
-    group = h // k.shape[2]
-    kf = jnp.repeat(k.astype(jnp.float32), group, axis=2)
-    vf = jnp.repeat(v.astype(jnp.float32), group, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) / jnp.sqrt(d), kf)
-    if softcap:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, vf).astype(q.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _pallas_attention(q, k, v, causal: bool, window: int, softcap: float,
-                      block_q: int, block_kv: int, interpret: bool):
+                      block_q: int, block_kv: int, interpret: bool,
+                      sm_scale):
     """Pallas flash attention on [B, S, H, D] operands, differentiable.
 
     The forward is the Pallas kernel, which also returns each row's
@@ -156,12 +58,12 @@ def _pallas_attention(q, k, v, causal: bool, window: int, softcap: float,
     ran.
     """
     out, _ = _pallas_fwd_impl(q, k, v, causal, window, softcap, block_q,
-                              block_kv, interpret)
+                              block_kv, interpret, sm_scale)
     return out
 
 
 def _pallas_fwd_impl(q, k, v, causal, window, softcap, block_q, block_kv,
-                     interpret):
+                     interpret, sm_scale):
     out, lse = flash_attention_pallas(
         q.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),
@@ -172,28 +74,30 @@ def _pallas_fwd_impl(q, k, v, causal, window, softcap, block_q, block_kv,
         block_q=block_q,
         block_kv=block_kv,
         interpret=interpret,
+        sm_scale=sm_scale,
     )
     return out.transpose(0, 2, 1, 3), lse
 
 
 def _pallas_fwd(q, k, v, causal, window, softcap, block_q, block_kv,
-                interpret):
+                interpret, sm_scale):
     out, lse = _pallas_fwd_impl(q, k, v, causal, window, softcap, block_q,
-                                block_kv, interpret)
+                                block_kv, interpret, sm_scale)
     return out, (q, k, v, out, lse)
 
 
-def _pallas_bwd(causal, window, softcap, block_q, block_kv, interpret, res,
-                gout):
+def _pallas_bwd(causal, window, softcap, block_q, block_kv, interpret,
+                sm_scale, res, gout):
     q, k, v, out, lse = res
     with jax.named_scope("flash_attention_bwd_blocked"):
         if window:
-            return _local_bwd(window, softcap, 0, block_q, (q, k, v), gout)
+            return _local_bwd(window, softcap, 0, block_q, sm_scale,
+                              (q, k, v), gout)
         b, h, sq = lse.shape
         kvh = k.shape[2]
         # [B, H, Sq] -> [B, KVH, G, Sq]: head h reads kv head h // G
         lse5 = lse.reshape(b, kvh, h // kvh, sq)
-        return _global_bwd(causal, softcap, 0, block_kv,
+        return _global_bwd(causal, softcap, 0, block_kv, sm_scale,
                            (q, k, v, out, lse5), gout)
 
 
@@ -250,8 +154,10 @@ def flash_attention(
     block_q: int = 512,
     block_kv: int = 512,
     interpret: bool = False,
+    sm_scale: Optional[float] = None,
 ) -> jax.Array:
-    """Dispatching attention entry point used by the models."""
+    """Dispatching attention entry point used by the models; the softmax
+    scale is ``sm_scale``, 1/sqrt(D) of the q/k heads unless given."""
     sq, sk = q.shape[1], k.shape[1]
     if impl is None:
         impl = default_attention_impl(sq, sk, window=window,
@@ -269,21 +175,22 @@ def flash_attention(
         bq, bk = min(block_q, sq), min(block_kv, sk)
         return _per_shard(
             lambda q, k, v: _pallas_attention(q, k, v, causal, window,
-                                              softcap, bq, bk, interpret),
+                                              softcap, bq, bk, interpret,
+                                              sm_scale),
             q, k, v,
         )
     if impl == "ref":
         return attention_reference(
             q, k, v, causal=causal, window=window, softcap=softcap,
-            q_offset=q_offset, kv_length=kv_length,
+            q_offset=q_offset, kv_length=kv_length, sm_scale=sm_scale,
         )
     if impl == "blocked_local":
         assert window and causal
         return flash_local(
-            q, k, v, window, softcap, q_offset, min(block_q, sq)
+            q, k, v, window, softcap, q_offset, min(block_q, sq), sm_scale
         )
     if impl == "blocked":
         return flash_global(
-            q, k, v, causal, softcap, q_offset, min(block_kv, sk)
+            q, k, v, causal, softcap, q_offset, min(block_kv, sk), sm_scale
         )
     raise ValueError(f"unknown impl {impl!r}")

@@ -16,19 +16,23 @@ import jax
 def attention_reference(
     q: jax.Array,            # [B, Sq, H, D]
     k: jax.Array,            # [B, Sk, KV, D]
-    v: jax.Array,            # [B, Sk, KV, D]
+    v: jax.Array,            # [B, Sk, KV, Dv]
     *,
     causal: bool = True,
     window: int = 0,          # 0 = unlimited; else causal sliding window
     softcap: float = 0.0,
     q_offset: int = 0,        # absolute position of q[0] (decode/prefill)
     kv_length: Optional[jax.Array] = None,  # valid kv prefix length [B]
+    sm_scale: Optional[float] = None,        # default 1/sqrt(D)
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, kv, _ = k.shape
     assert h % kv == 0
     group = h // kv
-    qf = q.astype(jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
+    if sm_scale is None:
+        qf = q.astype(jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
+    else:
+        qf = q.astype(jnp.float32) * sm_scale
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     # expand kv heads to full heads

@@ -60,9 +60,10 @@ from repro.elastic import (
     StragglerSlowdown,
 )
 from repro.models.model import init_params
+from repro.models.moe import STAT_KEYS
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.launch.compile_cache import enable_compile_cache
-from repro.obs import Tracer, format_event
+from repro.obs import Metrics, Tracer, format_event
 from repro.optim.optimizers import adamw
 from repro.sharding.specs import needs_fsdp
 from repro.train.bucketing import (
@@ -76,19 +77,19 @@ from repro.train.runtime import DeftRuntime, RuntimeConfig, make_ddp_step
 from repro.train.steps import init_train_state
 
 
-# Largest f32 logits block the LM head may hold at once; above it the
-# loss runs sequence-chunked (models.model.chunked_ce).
+# Largest f32 logits block the LM head may hold at once (the head and
+# loss run as models.model.chunked_ce, by sequence chunks of this size).
 LOGITS_BUDGET_BYTES = 256 * 2**20
 
 
 def loss_chunk_for(vocab_size: int, per_device_batch: int,
                    seq_len: int) -> int:
-    """Sequence chunk of the LM head + loss: 0 (whole sequence) when the
+    """Sequence chunk of the LM head + loss: the whole sequence when the
     ``[B, S, V]`` f32 logits fit :data:`LOGITS_BUDGET_BYTES`, else the
     largest power of two whose ``[B, chunk, V]`` block does."""
     row = 4 * vocab_size * per_device_batch
     if row * seq_len <= LOGITS_BUDGET_BYTES:
-        return 0
+        return seq_len
     chunk = 1
     while chunk * 2 * row <= LOGITS_BUDGET_BYTES and chunk * 2 < seq_len:
         chunk *= 2
@@ -528,6 +529,9 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
             signal.signal(signal.SIGUSR1, _on_preempt)
 
         losses, step_s, updated = [], [], []
+        # the expert layers' counters: the last step's (gauge) and their
+        # sum over the steps (counter)
+        expert_counts = Metrics()
         donation_held = True
         t0 = time.time()
         # a resumed run continues the data stream where it left off —
@@ -582,6 +586,10 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
             step_s.append(wall)
             losses.append(float(m["loss"]))
             updated.append(bool(m["updated"]))
+            for key in STAT_KEYS:
+                if key in m:
+                    expert_counts.set(key, float(m[key]))
+                    expert_counts.inc(key, float(m[key]))
             donation_held &= all(
                 x.is_deleted() for x in jax.tree_util.tree_leaves(prev)
             )
@@ -736,6 +744,7 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
         "donation_held": donation_held,
         "runtime": runtime,
         "loss_chunk": loss_chunk,
+        "expert_counts": expert_counts.summary(),
     }
 
 

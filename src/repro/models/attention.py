@@ -24,7 +24,12 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.sharded_decode import sharded_flash_decode
-from repro.models.common import apply_rope, dense_init, rms_norm_per_head
+from repro.models.common import (
+    apply_rope,
+    dense_init,
+    rms_norm_per_head,
+    yarn_mscale,
+)
 from repro.sharding import constrain
 from repro.util.flags import sharded_decode_enabled
 
@@ -237,20 +242,39 @@ def apply_cross_attention(
 # Multi-head Latent Attention (DeepSeek-V2)
 # ---------------------------------------------------------------------------
 def init_mla(key, cfg, dtype=jnp.float32) -> Dict:
+    """Latent attention's weights; without a q LoRA (``q_lora_rank``
+    None) q is one projection ``wq`` [d, H x (nope + rope)]."""
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
     keys = jax.random.split(key, 8)
+    if m.q_lora_rank is None:
+        q = {"wq": dense_init(keys[0], d, h * qk_head, dtype)}
+    else:
+        q = {
+            "wdq": dense_init(keys[0], d, m.q_lora_rank, dtype),
+            "q_norm": jnp.zeros((m.q_lora_rank,), dtype),
+            "wuq": dense_init(keys[1], m.q_lora_rank, h * qk_head, dtype),
+        }
     return {
-        "wdq": dense_init(keys[0], d, m.q_lora_rank, dtype),
-        "q_norm": jnp.zeros((m.q_lora_rank,), dtype),
-        "wuq": dense_init(keys[1], m.q_lora_rank, h * qk_head, dtype),
+        **q,
         "wdkv": dense_init(keys[2], d, m.kv_lora_rank + m.qk_rope_head_dim, dtype),
         "kv_norm": jnp.zeros((m.kv_lora_rank,), dtype),
         "wuk": dense_init(keys[3], m.kv_lora_rank, h * m.qk_nope_head_dim, dtype),
         "wuv": dense_init(keys[4], m.kv_lora_rank, h * m.v_head_dim, dtype),
         "wo": dense_init(keys[5], h * m.v_head_dim, d, dtype),
     }
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope)^-1/2, times mscale(factor, mscale_all_dim)^2 under
+    YaRN (HF ``DeepseekV2Attention.softmax_scale``)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 def make_mla_cache(cfg, batch: int, max_len: int, dtype=jnp.float32):
@@ -265,11 +289,14 @@ def _mla_q(p, x, cfg, qpos):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
-    cq = rms_norm_per_head(x @ p["wdq"], p["q_norm"])
-    q = (cq @ p["wuq"]).reshape(b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if m.q_lora_rank is None:
+        q = x @ p["wq"]
+    else:
+        q = rms_norm_per_head(x @ p["wdq"], p["q_norm"]) @ p["wuq"]
+    q = q.reshape(b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q = constrain(q, ("batch", None, "heads", None))
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope, qpos, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, qpos, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -279,7 +306,8 @@ def _mla_latent(p, x, cfg, kpos):
     ckv = rms_norm_per_head(dkv[..., : m.kv_lora_rank], p["kv_norm"])
     k_rope = dkv[..., m.kv_lora_rank :]
     # shared-across-heads rope key: add a singleton head dim for rotation
-    k_rope = apply_rope(k_rope[:, :, None, :], kpos, cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], kpos, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0, :]
     return ckv, k_rope
 
 
@@ -293,24 +321,30 @@ def apply_mla(
     kv_length: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict]]:
     """Train/prefill: expanded K/V. Decode (cache given): absorbed matmuls
-    against the latent cache."""
+    against the latent cache.  The latent projections and the rotation
+    run under the named scope ``mla``."""
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
     qpos = pos + jnp.arange(s)
-    q_nope, q_rope = _mla_q(p, x, cfg, qpos)
-    ckv, k_rope = _mla_latent(p, x, cfg, qpos)
+    sm_scale = mla_softmax_scale(cfg)
+    with jax.named_scope("mla"):
+        q_nope, q_rope = _mla_q(p, x, cfg, qpos)
+        ckv, k_rope = _mla_latent(p, x, cfg, qpos)
 
     if cache is None:
         # expanded path
-        k_nope = (ckv @ p["wuk"]).reshape(b, s, h, m.qk_nope_head_dim)
-        vv = (ckv @ p["wuv"]).reshape(b, s, h, m.v_head_dim)
-        k_full = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, m.qk_rope_head_dim))],
-            axis=-1,
-        )
-        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-        att = flash_attention(q_full, k_full, vv, causal=True)
+        with jax.named_scope("mla"):
+            k_nope = (ckv @ p["wuk"]).reshape(b, s, h, m.qk_nope_head_dim)
+            vv = (ckv @ p["wuv"]).reshape(b, s, h, m.v_head_dim)
+            k_full = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                          (b, s, h, m.qk_rope_head_dim))],
+                axis=-1,
+            )
+            q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+        att = flash_attention(q_full, k_full, vv, causal=True,
+                              sm_scale=sm_scale)
         out = att.reshape(b, s, -1) @ p["wo"]
         return constrain(out, ("batch", None, "embed")), None
 
@@ -323,11 +357,10 @@ def apply_mla(
     # absorb W_uk into q: q_lat [b, s, h, kv_lora]
     wuk = p["wuk"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bshd,lhd->bshl", q_nope, wuk)
-    scale = 1.0 / jnp.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     scores = (
         jnp.einsum("bshl,bkl->bhsk", q_lat.astype(jnp.float32), cckv.astype(jnp.float32))
         + jnp.einsum("bshd,bkd->bhsk", q_rope.astype(jnp.float32), ckrope.astype(jnp.float32))
-    ) * scale
+    ) * sm_scale
     kpos_all = jnp.arange(smax)
     valid = (kpos_all[None, :] <= qpos[:, None]) & (kpos_all[None, :] < length)
     scores = jnp.where(valid[None, None], scores, -1e30)

@@ -13,7 +13,8 @@ with gemma2-style post-norms when cfg.post_block_norm.  Variants:
                       channel-mix (token-shifted squared-relu MLP).
 
 ``apply_block`` threads an optional per-block cache (decode) and returns
-the MoE auxiliary loss (0 for dense).  All functions are shape-polymorphic
+the MoE auxiliary loss (0 for dense) and the expert layer's counters
+({} for dense; ``models/moe.py``).  All functions are shape-polymorphic
 over batch/sequence and contain no Python-level branching on traced
 values, so the same code lowers for train, prefill and decode.
 """
@@ -110,10 +111,10 @@ def apply_block(
     fill_cross_cache: bool = False,       # prefill: project+store memory kv
     causal: bool = True,
     kv_length: Optional[jax.Array] = None,
-    capacity_factor: float = 1.25,
-) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
+) -> Tuple[jax.Array, Optional[Dict], jax.Array, Dict[str, jax.Array]]:
     new_cache: Dict = dict(cache) if cache is not None else None
     aux = jnp.zeros((), jnp.float32)
+    stats: Dict[str, jax.Array] = {}
 
     def norm(name, h):
         return apply_norm(p[name], h, cfg.norm)
@@ -180,13 +181,13 @@ def apply_block(
         if st is not None:
             new_cache["state"] = st
     elif spec.ffn == "moe":
-        out, aux = apply_moe(p["ffn"], h, cfg=cfg, capacity_factor=capacity_factor)
+        out, aux, stats = apply_moe(p["ffn"], h, cfg=cfg)
     else:
         out = apply_ffn(p["ffn"], h, cfg)
     if cfg.post_block_norm:
         out = norm("post_ffn_norm", out)
     x = x + out
-    return x, new_cache, aux
+    return x, new_cache, aux, stats
 
 
 def _resolve_cross_kv(mixer_p, cache, new_cache, memory, cfg, fill):

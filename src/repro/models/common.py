@@ -65,24 +65,65 @@ def rms_norm_per_head(x: jax.Array, scale: Optional[jax.Array], eps: float = 1e-
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
-    """[head_dim/2] inverse frequencies."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (HF ``yarn_get_mscale``)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(scaling, head_dim: int, theta: float
+                          ) -> Tuple[int, int]:
+    """(low, high): the frequency indices where YaRN's ramp from the
+    unscaled to the interpolated frequencies starts and ends (HF
+    ``yarn_find_correction_range``)."""
+    def dim(rotations):
+        return (head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = math.floor(dim(scaling.beta_fast))
+    high = math.ceil(dim(scaling.beta_slow))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def rope_frequencies(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """[head_dim/2] inverse frequencies.  With YaRN ``scaling``
+    (:class:`~repro.configs.base.RopeScaling`) the frequencies below the
+    correction range keep theta's, those above it are divided by the
+    factor, and a linear ramp blends the two in between (HF
+    ``DeepseekV2YarnRotaryEmbedding``)."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta**exponent)
+    freqs = 1.0 / (theta**exponent)
+    if scaling is None:
+        return freqs
+    low, high = yarn_correction_range(scaling, head_dim, theta)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(
+        high - low, 1e-3)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return freqs / scaling.factor * (1.0 - keep) + freqs * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
     """x: [..., seq, n_heads, head_dim]; positions: [..., seq] int32.
 
-    Rotates pairs (x[2i], x[2i+1]) — NOT the half-split convention — which
-    matches the reference Griffin/Gemma implementations and is internally
-    self-consistent for train/prefill/decode.
+    Half-split rotation: element i of the first half and element i of
+    the second half rotate together as one pair, at frequency i.  With
+    YaRN ``scaling`` the frequencies are :func:`rope_frequencies`' and
+    cos/sin are scaled by mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim).
     """
     half = x.shape[-1] // 2
-    freqs = rope_frequencies(x.shape[-1], theta)  # [half]
+    freqs = rope_frequencies(x.shape[-1], theta, scaling)  # [half]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., seq, half]
     angles = angles[..., None, :]  # broadcast over heads
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            sin, cos = sin * m, cos * m
     x1, x2 = x[..., :half], x[..., half:]
     y1 = x1.astype(jnp.float32) * cos - x2.astype(jnp.float32) * sin
     y2 = x2.astype(jnp.float32) * cos + x1.astype(jnp.float32) * sin

@@ -40,6 +40,7 @@ from repro.models.common import (
     init_norm,
     softcap,
 )
+from repro.models.moe import add_stats, zero_stats
 from repro.sharding import constrain, shard_count
 from repro.util.flags import scan_unroll_enabled
 
@@ -181,7 +182,7 @@ def encode(params, cfg: ArchConfig, modal_embeds: jax.Array,
     spec = LayerSpec("attn", "dense")
 
     def body(x, block_p):
-        x, _, _ = apply_block(block_p, x, cfg=cfg, spec=spec, causal=False)
+        x, _, _, _ = apply_block(block_p, x, cfg=cfg, spec=spec, causal=False)
         return x, None
 
     x, _ = jax.lax.scan(
@@ -201,24 +202,26 @@ def forward(
     pos: int | jax.Array = 0,
     kv_length: Optional[jax.Array] = None,
     fill_cross_cache: bool = False,
-    capacity_factor: float = 1.25,
     remat: bool = True,
     head: bool = True,
     unroll: bool = False,
-) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
-    """Returns (logits [B,S,V], new_cache, aux_loss); with ``head=False``
-    the final-norm hidden states [B,S,d] replace the logits (the chunked
-    loss path applies the LM head itself)."""
+) -> Tuple[jax.Array, Optional[Dict], jax.Array, Dict[str, jax.Array]]:
+    """Returns (logits [B,S,V], new_cache, aux_loss, expert counters);
+    with ``head=False`` the final-norm hidden states [B,S,d] replace the
+    logits (the chunked loss path applies the LM head itself).  The
+    counters are the expert layers' (``moe.STAT_KEYS``; {} for a model
+    without experts)."""
     lay = stack_layout(cfg)
     x = params["embed"]["table"][tokens]
     if cfg.embedding_multiplier != 1.0:
         x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     x = constrain(x, ("batch", None, "embed"))
     aux = jnp.zeros((), jnp.float32)
+    stats = zero_stats(cfg)
 
     block_kw = dict(
         cfg=cfg, pos=pos, memory=memory, fill_cross_cache=fill_cross_cache,
-        kv_length=kv_length, capacity_factor=capacity_factor,
+        kv_length=kv_length,
     )
 
     def run_block(p, x, spec, c):
@@ -232,20 +235,23 @@ def forward(
     for i, spec in enumerate(lay.prefix_specs):
         spec_d = dataclasses.replace(spec, ffn="dense")
         c = cache["prefix"][i] if cache is not None else None
-        x, nc, a = maybe_ckpt(params["prefix"][i], x, spec_d, c)
+        x, nc, a, st = maybe_ckpt(params["prefix"][i], x, spec_d, c)
         new_prefix.append(nc)
         aux = aux + a
+        stats = add_stats(stats, st)
 
     def period_body(carry, xs):
-        x, aux = carry
+        x, aux, stats = carry
         stacked_p, stacked_c = xs
         new_cs = []
         for j in range(lay.period):
             c = stacked_c[j] if stacked_c is not None else None
-            x, nc, a = maybe_ckpt(stacked_p[j], x, cfg.layer_pattern[j], c)
+            x, nc, a, st = maybe_ckpt(stacked_p[j], x, cfg.layer_pattern[j],
+                                      c)
             aux = aux + a
+            stats = add_stats(stats, st)
             new_cs.append(nc if nc is not None else {})
-        return (x, aux), tuple(new_cs)
+        return (x, aux, stats), tuple(new_cs)
 
     new_stack = None
     if lay.n_periods:
@@ -253,23 +259,24 @@ def forward(
         xs = (tuple(params["stack"]), stacked_c)
         if cache is None:
             xs = (tuple(params["stack"]), None)
-            (x, aux), _ = jax.lax.scan(
-                lambda c, p: (period_body(c, (p, None))[0], None), (x, aux),
-                xs[0],
+            (x, aux, stats), _ = jax.lax.scan(
+                lambda c, p: (period_body(c, (p, None))[0], None),
+                (x, aux, stats), xs[0],
                 unroll=lay.n_periods if (unroll or scan_unroll_enabled()) else 1,
             )
         else:
-            (x, aux), new_stack = jax.lax.scan(
-                period_body, (x, aux), xs,
+            (x, aux, stats), new_stack = jax.lax.scan(
+                period_body, (x, aux, stats), xs,
                 unroll=lay.n_periods if (unroll or scan_unroll_enabled()) else 1,
             )
 
     new_tail = []
     for i, spec in enumerate(lay.tail_specs):
         c = cache["tail"][i] if cache is not None else None
-        x, nc, a = maybe_ckpt(params["tail"][i], x, spec, c)
+        x, nc, a, st = maybe_ckpt(params["tail"][i], x, spec, c)
         new_tail.append(nc)
         aux = aux + a
+        stats = add_stats(stats, st)
 
     x = apply_norm(params["final_norm"], x, cfg.norm)
     if not head:
@@ -280,7 +287,7 @@ def forward(
                 "stack": new_stack if new_stack is not None else cache["stack"],
                 "tail": tuple(new_tail),
             }
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
     logits = head_logits(params, cfg, x)
 
     new_cache = None
@@ -290,7 +297,7 @@ def forward(
             "stack": new_stack if new_stack is not None else cache["stack"],
             "tail": tuple(new_tail),
         }
-    return logits, new_cache, aux
+    return logits, new_cache, aux, stats
 
 
 # ---------------------------------------------------------------------------
@@ -466,7 +473,6 @@ def loss_fn(
     cfg: ArchConfig,
     batch: Dict[str, jax.Array],
     *,
-    capacity_factor: float = 1.25,
     remat: bool = True,
     loss_chunk: int = 0,
     unroll: bool = False,
@@ -474,57 +480,54 @@ def loss_fn(
     """Next-token cross entropy (+ MoE aux). batch: tokens, labels, and the
     optional stub-frontend 'memory' embeddings (audio frames / image
     patches).  ``loss_chunk > 0`` switches to the sequence-chunked LM-head
-    path (memory: see chunked_ce)."""
+    path (memory: see chunked_ce).  The metrics are ``ce``, ``aux`` and,
+    for a model with expert layers, their counters (``moe.STAT_KEYS``)."""
     memory = batch.get("memory")
     if cfg.is_encoder_decoder:
         memory = encode(params, cfg, memory, unroll=unroll)
     mask = batch.get("mask")
     mask = mask[:, 1:] if mask is not None else None
     if loss_chunk:
-        x, _, aux = forward(
-            params, cfg, batch["tokens"], memory=memory,
-            capacity_factor=capacity_factor, remat=remat, head=False,
-            unroll=unroll,
+        x, _, aux, stats = forward(
+            params, cfg, batch["tokens"], memory=memory, remat=remat,
+            head=False, unroll=unroll,
         )
         loss = chunked_ce(
             params, cfg, x[:, :-1], batch["labels"][:, 1:], mask, loss_chunk,
             unroll=unroll,
         )
     else:
-        logits, _, aux = forward(
-            params, cfg, batch["tokens"], memory=memory,
-            capacity_factor=capacity_factor, remat=remat, unroll=unroll,
+        logits, _, aux, stats = forward(
+            params, cfg, batch["tokens"], memory=memory, remat=remat,
+            unroll=unroll,
         )
         loss = cross_entropy_loss(
             logits[:, :-1], batch["labels"][:, 1:], mask
         )
-    return loss + aux, {"ce": loss, "aux": aux}
+    return loss + aux, {"ce": loss, "aux": aux, **stats}
 
 
 def prefill(
     params, cfg: ArchConfig, tokens: jax.Array, cache: Dict,
-    *, memory: Optional[jax.Array] = None, capacity_factor: float = 1.25,
-    unroll: bool = False,
+    *, memory: Optional[jax.Array] = None, unroll: bool = False,
 ) -> Tuple[jax.Array, Dict]:
     """Fill the cache with a prompt; returns (last-position logits, cache)."""
     if cfg.is_encoder_decoder and memory is not None:
         memory = encode(params, cfg, memory, unroll=unroll)
-    logits, cache, _ = forward(
+    logits, cache, _, _ = forward(
         params, cfg, tokens, memory=memory, cache=cache, pos=0,
-        fill_cross_cache=True, capacity_factor=capacity_factor, remat=False,
-        unroll=unroll,
+        fill_cross_cache=True, remat=False, unroll=unroll,
     )
     return logits[:, -1], cache
 
 
 def decode_step(
     params, cfg: ArchConfig, token: jax.Array, cache: Dict, pos: jax.Array,
-    *, kv_length: Optional[jax.Array] = None, capacity_factor: float = 1.25,
-    unroll: bool = False,
+    *, kv_length: Optional[jax.Array] = None, unroll: bool = False,
 ) -> Tuple[jax.Array, Dict]:
     """One decode step: token [B] int32, absolute position ``pos``."""
-    logits, cache, _ = forward(
+    logits, cache, _, _ = forward(
         params, cfg, token[:, None], cache=cache, pos=pos, kv_length=kv_length,
-        capacity_factor=capacity_factor, remat=False, unroll=unroll,
+        remat=False, unroll=unroll,
     )
     return logits[:, 0], cache

@@ -73,7 +73,8 @@ def ordered_leaf_indices(params) -> List[int]:
 
 def leaf_active_fraction(cfg: ArchConfig, keys: Tuple[str, ...]) -> float:
     """Fraction of a leaf's elements doing matmul work per token (MoE
-    routed experts: top-k of E)."""
+    routed experts: top-k of E, the router's width, for each expert the
+    layer holds)."""
     if cfg.moe and "experts" in keys and keys[-1] in ("gate", "up", "down"):
         return cfg.moe.experts_per_token / cfg.moe.n_experts
     return 1.0

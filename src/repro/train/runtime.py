@@ -717,7 +717,6 @@ def _deft_body_flat_rs(
 # tuple-shaped accumulators.
 _fused_state_specs = _state_specs
 
-_METRIC_SPECS = {"loss": P(), "ce": P(), "aux": P(), "updated": P(), "k": P()}
 
 
 def _flat_state_specs(state: TrainState, dp_axes: Tuple[str, ...]):
@@ -770,7 +769,9 @@ def _shard_phase(body, specs_fn, state, batch, mesh, dp_axes):
         body,
         mesh=mesh,
         in_specs=(state_specs, _batch_specs(batch, dp_axes)),
-        out_specs=(state_specs, _METRIC_SPECS),
+        # every metric (the loss's parts, a model's expert counters
+        # included) is a replicated scalar after the fused psum
+        out_specs=(state_specs, P()),
         axis_names=manual,
         check_vma=False,
     )(state, batch)

@@ -378,14 +378,12 @@ def deft_phase_step(
     )
     in_specs = (_state_specs(state, dp_axes), _batch_specs(batch, dp_axes))
     out_state_specs = _state_specs(state, dp_axes)
-    out_metric_specs = {
-        "loss": P(), "ce": P(), "aux": P(), "updated": P(), "k": P()
-    }
     return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
-        out_specs=(out_state_specs, out_metric_specs),
+        # every metric is a replicated scalar after its psum
+        out_specs=(out_state_specs, P()),
         axis_names=set(dp_axes),
         check_vma=False,
     )(state, batch)
@@ -428,14 +426,11 @@ def deft_rs_phase_step(
         unroll=unroll,
     )
     in_specs = (_state_specs(state, dp_axes), _batch_specs(batch, dp_axes))
-    out_metric_specs = {
-        "loss": P(), "ce": P(), "aux": P(), "updated": P(), "k": P()
-    }
     return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
-        out_specs=(_state_specs(state, dp_axes), out_metric_specs),
+        out_specs=(_state_specs(state, dp_axes), P()),
         axis_names=set(dp_axes),
         check_vma=False,
     )(state, batch)

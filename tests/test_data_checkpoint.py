@@ -65,7 +65,7 @@ def test_checkpoint_roundtrip(tmp_path):
 
 
 def test_registry_complete():
-    assert len(ARCH_NAMES) == 10
+    assert len(ARCH_NAMES) == 11
     assert len(SHAPES) == 4
     for name in ARCH_NAMES:
         cfg = get_config(name)

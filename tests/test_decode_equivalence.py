@@ -19,6 +19,7 @@ FAMILIES = [
     "qwen3-4b",           # dense GQA + qk-norm
     "gemma2-2b",          # local+global alternating + softcaps + post-norms
     "deepseek-v2-236b",   # MLA + MoE
+    "deepseek-v2-lite",   # MLA without a q LoRA, YaRN + MoE (no renorm)
     "rwkv6-1.6b",         # rwkv recurrence
     "recurrentgemma-9b",  # rglru + local attention hybrid
     "seamless-m4t-large-v2",   # enc-dec cross attention
@@ -44,24 +45,22 @@ def test_prefill_then_decode_matches_forward(arch):
     if cfg.is_encoder_decoder:
         enc_mem = encode(params, cfg, memory)
 
-    # MoE expert-capacity dropping depends on how many tokens share a
-    # dispatch (48-token forward vs 1-token decode) — a generous capacity
-    # factor removes drops from both paths so they must agree exactly.
-    cap = 16.0
-
-    # ground truth: full forward over all S positions
-    full_logits, _, _ = forward(params, cfg, tokens, memory=enc_mem,
-                                capacity_factor=cap)
+    # ground truth: full forward over all S positions.  The expert layer
+    # drops no token however many share a dispatch (48-token forward vs
+    # 1-token decode), so both paths must agree exactly.
+    full_logits, _, _, stats = forward(params, cfg, tokens, memory=enc_mem)
+    if cfg.moe is not None:
+        assert float(stats["moe_dropped"]) == 0.0
+        assert float(stats["moe_rows"]) > 0.0
 
     # prefill the first n_prefill tokens, then decode the rest one by one
     cache = init_cache(cfg, B, S, prefill_chunk=n_prefill)
     last, cache = prefill(params, cfg, tokens[:, :n_prefill], cache,
-                          memory=memory, capacity_factor=cap)
+                          memory=memory)
     got = [last]
     for i in range(n_prefill, S):
         logits, cache = decode_step(params, cfg, tokens[:, i],
-                                    cache, jnp.asarray(i),
-                                    capacity_factor=cap)
+                                    cache, jnp.asarray(i))
         got.append(logits)
     got = jnp.stack(got, axis=1)  # positions n_prefill-1 .. S-1
 
@@ -81,7 +80,7 @@ def test_sliding_window_ring_cache_long_decode():
     B = 1
     S = cfg.sliding_window * 2 + 7   # well past the ring size
     tokens = jax.random.randint(key, (B, S), 0, cfg.vocab_size)
-    full_logits, _, _ = forward(params, cfg, tokens)
+    full_logits, _, _, _ = forward(params, cfg, tokens)
 
     cache = init_cache(cfg, B, S, prefill_chunk=1)
     logits, cache = prefill(params, cfg, tokens[:, :1], cache)

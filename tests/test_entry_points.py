@@ -76,12 +76,11 @@ def test_main_returns_what_each_step_measured(scheduler, monkeypatch):
 ])
 def test_loss_chunk_bounds_the_logits_block(vocab, batch, seq):
     chunk = loss_chunk_for(vocab, batch, seq)
-    block = 4 * vocab * batch * (chunk or seq)
+    block = 4 * vocab * batch * chunk
     assert block <= LOGITS_BUDGET_BYTES
-    if chunk:
-        assert chunk < seq and seq % chunk == 0
-        # the largest power of two that fits
-        assert 2 * block > LOGITS_BUDGET_BYTES or 2 * chunk >= seq
+    assert 0 < chunk <= seq and seq % chunk == 0
+    # the whole sequence, or the largest power of two that fits
+    assert chunk == seq or 2 * block > LOGITS_BUDGET_BYTES
 
 
 def test_compile_cache_placement(monkeypatch):

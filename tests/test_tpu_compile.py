@@ -221,3 +221,64 @@ def test_flat_phase_names_buckets_links_and_update_on_four_chips(topo):
         r"\"tpu_custom_call\".*?op_name=\"([^\"]*)\"", text, re.M)
     assert kernels and all(innermost(op) == "deft_update"
                            for _, op in kernels), kernels
+
+
+def _replica_groups(line: str):
+    """The device groups of one collective in compiled HLO text, in the
+    iota form (``[2,2]<=[2,2]T(1,0)``) or the listed one."""
+    m = re.search(r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\]"
+                  r"(?:T\(([\d,]+)\))?", line)
+    if m:
+        ids = np.arange(4).reshape([int(x) for x in m[3].split(",")])
+        if m[4]:
+            ids = ids.transpose([int(x) for x in m[4].split(",")])
+        return {frozenset(g) for g in
+                ids.reshape(int(m[1]), int(m[2])).tolist()}
+    m = re.search(r"replica_groups=\{((?:\{[\d,]+\},?)+)\}", line)
+    return {frozenset(int(x) for x in g.split(","))
+            for g in re.findall(r"\{([\d,]+)\}", m[1])}
+
+
+def test_expert_weights_stay_on_their_model_shard_on_four_chips(topo):
+    """The DDP step's form of an expert-parallel model (deepseek-v2-236b
+    at smoke widths, one jit over data 2 x model 2, experts split over
+    'model'): the compiled step gathers no tensor of the expert layer
+    across 'model' — each chip runs its own experts on its own tokens
+    and only activations are summed across the expert shards."""
+    from jax.sharding import AxisType
+
+    from repro.configs import get_config, reduce_for_smoke
+    from repro.models.model import init_params, loss_fn
+    from repro.sharding.specs import param_rules, spec_tree
+
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(2, 2),
+                             ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+    cfg = reduce_for_smoke(get_config("deepseek-v2-236b"))
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    specs = spec_tree(params, param_rules(cfg.name, False, "tp"), mesh)
+    for w in jax.tree.leaves(specs["stack"][0]["ffn"]["experts"]):
+        assert w[1] == "model"      # [layers, experts, ...]
+    p_in = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        params, specs)
+    tok = jax.ShapeDtypeStruct((4, 32), jnp.int32,
+                               sharding=NamedSharding(mesh,
+                                                      PartitionSpec("data")))
+
+    def loss(p, b):
+        with logical_rules(rules_pjit(False, False)):
+            return loss_fn(p, cfg, b)[0]
+
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss)).lower(
+            p_in, {"tokens": tok, "labels": tok}).compile().as_text()
+    # devices in mesh order: a 'model' group is a row of the mesh
+    over_model = {frozenset({0, 1}), frozenset({2, 3})}
+    gathers = [line for line in text.splitlines()
+               if re.search(r"all-gather(-start)?\(", line)
+               and re.search(r'op_name="[^"]*/moe/(?!moe_router)', line)]
+    assert gathers      # the FSDP gathers over 'data' are there
+    across = [line for line in gathers if _replica_groups(line) == over_model]
+    assert not across, across[0][:300]
