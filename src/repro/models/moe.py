@@ -26,6 +26,17 @@ sends them the row of zeros after the last token, and the combine reads
 only routed rows, so what a grouped matmul leaves in them cannot reach
 the output or a gradient.
 
+Backward: both row gathers (:func:`take_rows`) are differentiated by
+gathers through the map the other way, which ``_dispatch`` already
+builds, and not by autodiff's scatter-adds.  The dispatch's cotangent
+reaches each token as the sum of its k buffer rows, read through
+``pos`` (a choice held elsewhere reads a zero row); the combine's
+reaches each buffer row from the one pair that reads it, through the
+sorted ``order`` (a row past the routed ones reads a zero row).  A
+scatter-add would send every padding row (80-90% of the buffer at one
+chip's share of the experts) into one sentinel row, and a TPU serialises
+updates that collide.
+
 Expert parallelism: where the ambient mesh splits the held experts over
 auto axes (the 'experts' rule of a plain jit), dispatch, experts and
 combine run per device in a shard_map: each device takes its own slice
@@ -141,11 +152,44 @@ def aux_loss(probs: jax.Array, top_e: jax.Array, me, groups: int
     return me.router_aux_coef * jnp.mean(jnp.sum(f * mean_p, axis=-1))
 
 
+@jax.custom_vjp
+def take_rows(x: jax.Array, idx: jax.Array, inv: jax.Array) -> jax.Array:
+    """``concatenate([x, 0])[idx]``: rows of ``x`` [n, d], index n for a
+    row of zeros, whose backward is a gather too.  ``inv`` [n, J] lists,
+    for each row of ``x``, the output rows that read it, and the output's
+    row count (a zero row) in its unused entries; the gradient of row s
+    is then ``sum_j g[inv[s, j]]``, summed in f32 where J > 1.  Autodiff
+    would make a scatter-add of ``g`` into ``x``'s rows plus one, which
+    on a TPU serialises the updates that collide, and every output row
+    that reads the zero row collides."""
+    return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])[idx]
+
+
+def _take_rows_fwd(x, idx, inv):
+    return take_rows(x, idx, inv), inv
+
+
+def _take_rows_bwd(inv, g):
+    g = jnp.concatenate([g, jnp.zeros((1,) + g.shape[1:], g.dtype)])
+    if inv.shape[1] == 1:
+        return g[inv[:, 0]], None, None
+    # one [n, d] f32 sum of J gathers, not a [n, J, d] gather
+    acc = g[inv[:, 0]].astype(jnp.float32)
+    for j in range(1, inv.shape[1]):
+        acc = acc + g[inv[:, j]]
+    return acc.astype(g.dtype), None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 def _dispatch(top_e: jax.Array, held: int, offset):
     """Sorted rows of the pairs whose expert is one of ids ``offset`` ..
-    ``offset + held - 1``.  Returns (token of each buffer row, T for a
-    row past the routed ones; rows per held expert; buffer row of each
-    pair, the buffer's size for a pair held elsewhere; counters)."""
+    ``offset + held - 1``.  Returns (the pair t * k + j held in each
+    buffer row, T * k for a row past the routed ones; rows per held
+    expert; buffer row of each pair, the buffer's size for a pair held
+    elsewhere; counters).  Each is the other's inverse on the routed
+    rows."""
     t, k = top_e.shape
     rows = t * min(k, held)
     local = top_e.reshape(-1) - offset
@@ -154,7 +198,7 @@ def _dispatch(top_e: jax.Array, held: int, offset):
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((held,), jnp.int32).at[key].add(1, mode="drop")
     n_mine = jnp.sum(sizes)
-    token = jnp.where(jnp.arange(rows) < n_mine, order[:rows] // k, t)
+    pair = jnp.where(jnp.arange(rows) < n_mine, order[:rows], t * k)
     # each pair's row in the buffer: the inverse of the sort
     where = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
@@ -164,7 +208,7 @@ def _dispatch(top_e: jax.Array, held: int, offset):
         "moe_max_rows": jnp.max(sizes).astype(jnp.float32),
         "moe_dropped": jnp.sum(mine & (where >= rows)).astype(jnp.float32),
     }
-    return token, sizes, pos, stats
+    return pair, sizes, pos, stats
 
 
 def routed_experts(xt: jax.Array, top_p: jax.Array, top_e: jax.Array,
@@ -173,21 +217,23 @@ def routed_experts(xt: jax.Array, top_p: jax.Array, top_e: jax.Array,
     """The part of the layer's output [T, d] (f32) that the experts ``ex``
     ([held, ...] each), router ids ``offset`` onward, compute for the
     tokens ``xt``, and the counters."""
-    d = xt.shape[-1]
+    (t, k), d = top_e.shape, xt.shape[-1]
     with jax.named_scope("moe_dispatch"):
-        token, sizes, pos, stats = _dispatch(top_e, ex["gate"].shape[0],
-                                             offset)
-        # the row after the last token is zeros: rows past the routed
-        # ones read it
-        xs = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[token]
+        pair, sizes, pos, stats = _dispatch(top_e, ex["gate"].shape[0],
+                                            offset)
+        # rows past the routed ones read token T, a row of zeros; a
+        # token's rows in the buffer are its pos, where a choice held
+        # elsewhere names the buffer's size, a row of zeros too
+        xs = take_rows(xt, pair // k, pos)
     with jax.named_scope("moe_experts"):
         gate = jax.lax.ragged_dot(xs, ex["gate"], sizes)
         up = jax.lax.ragged_dot(xs, ex["up"], sizes)
         ys = jax.lax.ragged_dot(gated_act(act, gate, up), ex["down"], sizes)
     with jax.named_scope("moe_combine"):
         w = jnp.where(pos < ys.shape[0], top_p, 0.0)
-        ys = jnp.concatenate([ys, jnp.zeros((1, d), ys.dtype)])
-        y = jnp.einsum("tk,tkd->td", w, ys[pos],
+        # a routed row is read by its pair alone, a row past them by none
+        g = take_rows(ys, pos.reshape(-1), pair[:, None]).reshape(t, k, d)
+        y = jnp.einsum("tk,tkd->td", w, g,
                        preferred_element_type=jnp.float32)
     return y, stats
 

@@ -97,6 +97,63 @@ def test_no_token_dropped_when_every_choice_is_held():
                                rtol=1e-5, atol=1e-5)
 
 
+def _gathers_by_autodiff(xt, top_p, top_e, ex, offset):
+    """The expert layer with its two row gathers left to autodiff, whose
+    backward passes are scatter-adds."""
+    (t, k), d = top_e.shape, xt.shape[-1]
+    pair, sizes, pos, _ = moe._dispatch(top_e, ex["gate"].shape[0], offset)
+    xs = jnp.concatenate([xt, jnp.zeros((1, d))])[pair // k]
+    h = gated_act("silu", jax.lax.ragged_dot(xs, ex["gate"], sizes),
+                  jax.lax.ragged_dot(xs, ex["up"], sizes))
+    ys = jax.lax.ragged_dot(h, ex["down"], sizes)
+    ys = jnp.concatenate([ys, jnp.zeros((1, d))])
+    w = jnp.where(pos < ys.shape[0] - 1, top_p, 0.0)
+    return jnp.einsum("tk,tkd->td", w, ys[pos])
+
+
+@pytest.mark.parametrize("held,offset,k,held_only", [
+    (8, 0, 6, False),      # held >= k, most of the buffer padding
+    (8, 0, 6, True),       # every choice held: the buffer full
+    (4, 0, 6, False),      # held < k: a buffer of T * held rows
+    (8, 24, 6, False),     # a share at offset 24
+    (8, 8, 1, False),      # top-1
+])
+def test_expert_layer_backward_by_gathers_matches_autodiff(held, offset, k,
+                                                           held_only):
+    """The expert layer's value and its gradients in the tokens, the
+    routing weights and each expert tensor, with both row gathers
+    differentiated by gathers (``moe.take_rows``), equal those that
+    autodiff's scatter-adds give."""
+    t, e, d, de = 24, 32, 16, 8
+    keys = jax.random.split(jax.random.PRNGKey(8), 6)
+    # each token's k distinct experts, from the held ones alone if asked
+    ids = jnp.arange(offset, offset + held) if held_only else jnp.arange(e)
+    top_e = jax.vmap(lambda kk: jax.random.permutation(kk, ids)[:k])(
+        jax.random.split(keys[0], t))
+    top_p = jax.nn.softmax(jax.random.normal(keys[1], (t, k)), -1)
+    xt = jax.random.normal(keys[2], (t, d))
+    ex = {"gate": jax.random.normal(keys[3], (held, d, de)),
+          "up": jax.random.normal(keys[4], (held, d, de)),
+          "down": jax.random.normal(keys[5], (held, de, d)) / 4}
+    cot = jax.random.normal(jax.random.PRNGKey(9), (t, d))
+
+    def loss(fn):
+        return lambda xt, top_p, ex: jnp.sum(fn(xt, top_p, top_e, ex) * cot)
+
+    ours = loss(lambda *a: moe.routed_experts(*a, offset, "silu")[0])
+    plain = loss(lambda *a: _gathers_by_autodiff(*a, offset))
+    rows = float(moe.routed_experts(xt, top_p, top_e, ex, offset,
+                                    "silu")[1]["moe_rows"])
+    assert 0 < rows <= t * min(k, held)
+    assert (rows == t * k) == held_only
+    (v, g), (v0, g0) = (jax.value_and_grad(f, (0, 1, 2))(xt, top_p, ex)
+                        for f in (ours, plain))
+    assert float(v) == float(v0)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
+
+
 def test_yarn_frequency_mask_has_the_published_ramp():
     rs = LITE.rope_scaling
     assert yarn_correction_range(rs, 64, 10_000.0) == (10, 23)

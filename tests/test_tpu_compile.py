@@ -223,6 +223,54 @@ def test_flat_phase_names_buckets_links_and_update_on_four_chips(topo):
                            for _, op in kernels), kernels
 
 
+def test_expert_layer_backward_gathers_rows_on_one_chip(one_chip):
+    """DeepSeek-V2-Lite's expert layer at one chip's share (T 4,096, d
+    2,048, dₑ 1,408, 8 of 64 experts held, top-6, bf16): its compiled
+    value-and-gradient scatters no rows of width 2,048, and the
+    backward's row gathers, with the fusions that hold them, run under
+    the layer's dispatch and combine scopes, where ``moe_ms`` finds
+    them."""
+    from repro.configs import get_config
+    from repro.models import moe
+
+    lite = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(
+        lite, moe=dataclasses.replace(lite.moe, experts_held=8))
+    p = jax.eval_shape(
+        lambda: moe.init_moe(jax.random.PRNGKey(0), cfg, jnp.bfloat16))
+    p = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), p)
+    x = _spec(one_chip, (1, 4096, cfg.d_model), jnp.bfloat16)
+
+    def loss(p, x):
+        # inside an outer scope, as the layer runs in the step: autodiff
+        # then wraps that scope's name and leaves ``moe`` a name of its own
+        with jax.named_scope("step"):
+            y, aux, _ = moe.apply_moe(p, x, cfg=cfg)
+            return jnp.sum(y.astype(jnp.float32)) + aux
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        p, x).compile().as_text()
+    rows = r"= \w+\[\d+,2048\]\S* "
+    scatters = [line for line in text.splitlines()
+                if re.search(rows + r"scatter\(", line)]
+    assert not scatters, scatters[0][:300]
+    # the backward's row gathers, by the computation that holds each
+    back, comp = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if m:
+            comp = m[1]
+        m = re.search(rows + r"gather\(.*op_name=\"([^\"]*)\"", line)
+        if m and "transpose(" in m[1]:
+            back[m[1]] = comp
+    scopes = {s for op in back for s in ("moe/moe_dispatch/",
+                                         "moe/moe_combine/") if s in op}
+    assert scopes == {"moe/moe_dispatch/", "moe/moe_combine/"}, back
+    callers = re.findall(r"calls=%([\w.-]+).*?op_name=\"([^\"]*)\"", text)
+    held = [op for c, op in callers if c in set(back.values())]
+    assert held and all("moe" in op.split("/") for op in held), held
+
+
 def _replica_groups(line: str):
     """The device groups of one collective in compiled HLO text, in the
     iota form (``[2,2]<=[2,2]T(1,0)``) or the listed one."""
